@@ -32,6 +32,7 @@ from repro.core import LookupService, Service  # noqa: E402
 from repro.sim import SimCluster  # noqa: E402
 from repro.tune import (DEFAULTS, KernelTuner, TuningCache,  # noqa: E402
                         best_config, measure_candidate, set_cache)
+from repro.tune.cache import device_platform  # noqa: E402
 
 #: (kernel, shape) pairs for the real CPU sweep — XLA-path kernels only
 #: (the Pallas kernels interpret on CPU; timing them times the emulator).
@@ -109,7 +110,7 @@ def bench_overhead(real_rows, *, probes=20_000) -> dict:
     row = real_rows[kernel]
     cache = TuningCache()
     cache.put(kernel, row["shape"], "float32", "xla", row["config"], 1.0,
-              save=False)
+              platform=device_platform(), save=False)
     set_cache(cache)
     try:
         default = DEFAULTS[kernel]

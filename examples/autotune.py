@@ -1,13 +1,15 @@
-"""Tune a kernel on the farm, then serve through the cached config.
+"""Tune a kernel on the farm, persist the winner, read it back at dispatch.
 
 The autotuner IS a farm application — the purest embarrassingly-parallel
 workload there is: N independent (compile a candidate, time it, report a
 number) tasks.  This example runs a successive-halving sweep over a
 deterministic ``sim://`` cluster with the scripted cost model (so it
 finishes in seconds and picks the same winner every run), persists the
-winner to a JSON cache, and then calls the model-side dispatch — which
-silently picks the tuned chunking up from the cache, zero call-site
-changes.
+winner to a JSON cache, and then calls the model-side dispatch, which
+reads the cache with zero call-site changes.  A winner is keyed by the
+platform it was timed on; the cost model's is keyed ``sim``, so dispatch
+on a real device leaves it alone (a cache miss) — a sweep with
+``cost_model=None`` on services that own the device is what steers it.
 
     PYTHONPATH=src python examples/autotune.py
 """
@@ -44,8 +46,8 @@ def main():
     entry = json.load(open(cache_path))
     print(f"cache {cache_path}: {list(entry['entries'])}")
 
-    # 3. serve through it: install the cache and call dispatch — the
-    #    tuned q_chunk/kv_chunk apply with no call-site changes
+    # 3. dispatch reads the cache: a winner timed on this platform would
+    #    apply with no call-site changes; the cost model's does not
     configure(cache_path)
     from repro.kernels import flash_attention_dispatch
 
@@ -55,7 +57,7 @@ def main():
     v = jax.random.normal(kv, (1, 1024, 2, 64), jnp.float32)
     out = flash_attention_dispatch(q, k, v, causal=True)
     c = get_cache()
-    print(f"dispatch through tuned config: out {out.shape}, "
+    print(f"dispatch on {jax.devices()[0].platform}: out {out.shape}, "
           f"cache hits={c.hits} misses={c.misses}")
 
 

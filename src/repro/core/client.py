@@ -29,7 +29,9 @@ Teardown keeps the two historical contracts:
 
 - **success** releases every service the moment the last result is in
   (``shutdown(join=False)``) — trailing speculative duplicates must not
-  stretch the makespan;
+  stretch the makespan; :meth:`BasicClient.join` waits them out where
+  the caller needs the services quiet (before the next call on the same
+  device, or before the process exits);
 - **abort** (timeout, program error) clock-aware-joins the control
   threads first, then releases exactly once — a timed-out client must
   never hand a still-busy service back to a shared pool.
@@ -180,6 +182,15 @@ class BasicClient:
         results = self.repository.results()
         self.output[:] = results
         return self.output
+
+    def join(self, timeout: float = 60.0) -> None:
+        """Wait out the trailing speculative duplicates that
+        :meth:`compute` left running when it returned, so none still
+        holds a device (or runs inside XLA at interpreter exit).  Raises
+        ``TimeoutError`` if one is still running after ``timeout``."""
+        if not self.engine.join(timeout):
+            raise TimeoutError(f"a control thread still runs {timeout} s "
+                               f"after compute() returned")
 
     def stats(self) -> dict:
         s = self.repository.stats()

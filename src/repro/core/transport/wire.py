@@ -9,8 +9,9 @@ the way out (that device→host copy *is* the real serialization cost the
 in-process backend never pays), everything else pickles as-is.
 
 Programs cross the wire once per (connection, program): ``fn`` is
-cloudpickled (lambdas and closures included), the rest of the ``Program``
-constructor arguments ride alongside.  msgpack and cloudpickle are both
+cloudpickled (lambdas and closures included), its resident state as a
+pytree, and the rest of the ``Program`` constructor arguments ride
+alongside.  msgpack and cloudpickle are both
 optional — without msgpack the envelope falls back to pickle (same frame
 layout), without cloudpickle only importable module-level functions can be
 shipped to ``proc`` workers.
@@ -161,7 +162,8 @@ def dump_program(program) -> dict:
                 f"cannot serialize program {program.name!r} for a proc "
                 f"worker without cloudpickle: {e}") from e
     return {"uid": program.uid, "name": program.name, "fn": fn_bytes,
-            "jit": program._jit, "static": list(program._static)}
+            "jit": program._jit, "static": list(program._static),
+            "resident": dump_pytree(program.resident)}
 
 
 def load_program(desc: dict):
@@ -169,4 +171,5 @@ def load_program(desc: dict):
 
     fn = pickle.loads(desc["fn"])  # cloudpickle output loads via pickle
     return Program(fn, name=desc["name"], jit=desc["jit"],
-                   static_argnames=tuple(desc["static"]))
+                   static_argnames=tuple(desc["static"]),
+                   resident=load_pytree(desc["resident"]))

@@ -246,6 +246,19 @@ class FarmScheduler:
             self._assignment.clear()
         self.pool.release_all()
 
+    def join(self, timeout_s: float) -> bool:
+        """After :meth:`shutdown`, wait (up to ``timeout_s``) for every
+        control thread to exit — including one still running a trailing
+        speculative duplicate after ``shutdown(join=False)``.  Returns
+        True once none is left, so no task of this engine still holds a
+        device."""
+        with self._lock:
+            threads = list(self._threads.values())
+            if self._rebalancer is not None:
+                threads.append(self._rebalancer)
+        clock_join(self.clock, threads, timeout_s)
+        return not any(t.is_alive() for t in threads)
+
     # ---------------- pool membership ------------------------------ #
     def _service_joined(self, sid: str, handle: ServiceHandle) -> None:
         # ServicePool.on_join — under the scheduler lock

@@ -56,10 +56,7 @@ def decode_attention_tp(q, k_cache, v_cache, *, cache_index, window=None):
     H = q.shape[2]
     from repro.models.attention import decode_attention_xla
 
-    if (mesh is None or axes is None or "model" not in mesh.axis_names
-            or not hasattr(jax, "shard_map")):
-        # jax<0.5 shard_map makes every mesh axis manual, which conflicts
-        # with the models' inner sharding constraints — use GSPMD there.
+    if mesh is None or axes is None or "model" not in mesh.axis_names:
         return decode_attention_xla(q, k_cache, v_cache,
                                     cache_index=cache_index, window=window)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -87,14 +84,7 @@ def decode_attention_tp(q, k_cache, v_cache, *, cache_index, window=None):
         return out[:, None].astype(v_.dtype)  # (B,1,H,Dv)
 
     ci = jnp.asarray(cache_index, jnp.int32)
-    try:
-        smap = jax.shard_map(local, mesh=mesh,
-                             in_specs=(q_spec, kv_spec, kv_spec, idx_spec),
-                             out_specs=q_spec, check_vma=False)
-    except TypeError:  # older jax.shard_map signature (check_rep, not check_vma)
-        from jax.experimental.shard_map import shard_map as _sm
-
-        smap = _sm(local, mesh=mesh,
-                   in_specs=(q_spec, kv_spec, kv_spec, idx_spec),
-                   out_specs=q_spec, check_rep=False)
-    return smap(q, k_cache, v_cache, ci)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec, idx_spec),
+                         out_specs=q_spec, check_vma=False)(
+                             q, k_cache, v_cache, ci)

@@ -21,8 +21,14 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0e38
+
+# (b, h, q-block) grid dims are independent; the last is the sequential
+# reduction that carries the online-softmax state in scratch.
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -49,12 +55,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-        acc_scr[...] = (acc_scr[...] * corr[:, None]
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * corr
                         + jax.lax.dot_general(
                             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -71,7 +77,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(kb == n_kv_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-37)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
@@ -102,6 +108,10 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         interpret: bool = False, return_lse: bool = False):
     """q: (B, Sq, H, D); k, v: (B, Skv, K, D) with H % K == 0.
     Returns (B, Sq, H, D) in q.dtype [, lse (B, H, Sq) fp32].
+
+    Row statistics (lse, and the backward's Dvec) travel as (B, H, Sq, 1)
+    columns: a block's last dim must be a multiple of 128 or the whole
+    dim, and its second-to-last a multiple of 8, on the chip.
 
     ``block_q``/``block_k`` default to the tuned config for this shape
     bucket (``repro.tune`` cache; 128/128 when untuned); explicit values
@@ -137,26 +147,23 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, Dv),
                          lambda b, h, qb, kb: (b, h, qb, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qb, kb: (b, h, qb)),
+            _row_spec(block_q),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q, Dv), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(qt, kt, vt)
     out = out.transpose(0, 2, 1, 3)
     if return_lse:
-        return out, lse
+        return out, lse[..., 0]
     return out
 
 
@@ -187,10 +194,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, dq_ref,
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
+        p = jnp.exp(s - lse_ref[0, 0])  # lse: (bq, 1)
         dp = jax.lax.dot_general(gv, vv, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - d_ref[0, 0][:, None]) * scale
+        ds = p * (dp - d_ref[0, 0]) * scale
         acc_scr[...] += jax.lax.dot_general(
             ds, kv, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -230,13 +237,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref,
                 k_pos = kb * block_k + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
                 s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-            p = jnp.exp(s - lse_ref[0, g][:, None])  # (bq, bk)
+            p = jnp.exp(s - lse_ref[0, g])  # (bq, bk)
             dv_scr[...] += jax.lax.dot_general(
                 p, gv, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(gv, vv, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-            ds = p * (dp - d_ref[0, g][:, None]) * scale
+            ds = p * (dp - d_ref[0, g]) * scale
             dk_scr[...] += jax.lax.dot_general(
                 ds, qv, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -272,7 +279,9 @@ def flash_attention_bwd(q, k, v, out, lse, g, *, causal=True, block_q=None,
     vt = v.transpose(0, 2, 1, 3)
     gt = g.transpose(0, 2, 1, 3)
     Dvec = jnp.sum(gt.astype(jnp.float32)
-                   * out.transpose(0, 2, 1, 3).astype(jnp.float32), axis=-1)
+                   * out.transpose(0, 2, 1, 3).astype(jnp.float32), axis=-1,
+                   keepdims=True)  # (B, H, Sq, 1)
+    lse = lse[..., None]
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
@@ -287,17 +296,14 @@ def flash_attention_bwd(q, k, v, out, lse, g, *, causal=True, block_q=None,
             pl.BlockSpec((1, 1, block_k, Dv),
                          lambda b, h, qb, kb, K=K, H=H: (b, h * K // H, kb, 0)),
             pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, qb, kb: (b, h, qb, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qb, kb: (b, h, qb)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qb, kb: (b, h, qb)),
+            _row_spec(block_q),
+            _row_spec(block_q),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D),
                                lambda b, h, qb, kb: (b, h, qb, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-        scratch_shapes=[_vmem((block_q, D), jnp.float32)],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "parallel", "arbitrary"))
-        ) if not interpret else None,
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(qt, kt, vt, gt, lse, Dvec)
 
@@ -317,8 +323,10 @@ def flash_attention_bwd(q, k, v, out, lse, g, *, causal=True, block_q=None,
                          lambda b, kv, kb, qb: (b, kv, kb, 0)),
             pl.BlockSpec((1, G, block_q, Dv),
                          lambda b, kv, kb, qb: (b, kv, qb, 0)),
-            pl.BlockSpec((1, G, block_q), lambda b, kv, kb, qb: (b, kv, qb)),
-            pl.BlockSpec((1, G, block_q), lambda b, kv, kb, qb: (b, kv, qb)),
+            pl.BlockSpec((1, G, block_q, 1),
+                         lambda b, kv, kb, qb: (b, kv, qb, 0)),
+            pl.BlockSpec((1, G, block_q, 1),
+                         lambda b, kv, kb, qb: (b, kv, qb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D),
@@ -330,19 +338,15 @@ def flash_attention_bwd(q, k, v, out, lse, g, *, causal=True, block_q=None,
             jax.ShapeDtypeStruct((B, K, Skv, D), k.dtype),
             jax.ShapeDtypeStruct((B, K, Skv, Dv), v.dtype),
         ],
-        scratch_shapes=[_vmem((block_k, D), jnp.float32),
-                        _vmem((block_k, Dv), jnp.float32)],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "parallel", "arbitrary"))
-        ) if not interpret else None,
+        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(qt, kt, vt, gt, lse, Dvec)
     return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
             dv.transpose(0, 2, 1, 3))
 
 
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
+def _row_spec(block_q):
+    """(1, 1, block_q, 1) tile of a (B, H, Sq, 1) row statistic."""
+    return pl.BlockSpec((1, 1, block_q, 1), lambda b, h, qb, kb: (b, h, qb, 0))

@@ -100,10 +100,7 @@ def flash_attention_tp(q, k, v, *, causal=True, window=None,
     axes = current_axes()
     B, Sq, H, Dq = q.shape
     K = k.shape[2]
-    if (mesh is None or axes is None or "model" not in mesh.axis_names
-            or not hasattr(jax, "shard_map")):
-        # jax<0.5 shard_map makes every mesh axis manual, which conflicts
-        # with the models' inner sharding constraints — use GSPMD there.
+    if mesh is None or axes is None or "model" not in mesh.axis_names:
         return flash_attention_xla(q, k, v, causal, window, q_chunk, kv_chunk)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     tp = sizes.get("model", 1)
@@ -125,15 +122,8 @@ def flash_attention_tp(q, k, v, *, causal=True, window=None,
         return flash_attention_xla(q_, k_, v_, causal, window, q_chunk,
                                    kv_chunk)
 
-    try:
-        smap = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_vma=False)
-    except TypeError:  # older jax.shard_map signature (check_rep, not check_vma)
-        from jax.experimental.shard_map import shard_map as _sm
-
-        smap = _sm(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_rep=False)
-    out = smap(q, k, v)
+    out = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                        out_specs=spec, check_vma=False)(q, k, v)
     if plan is not None:
         out = jnp.take(out, jnp.asarray(plan.inv), axis=2)
     return out

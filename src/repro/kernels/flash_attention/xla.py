@@ -32,7 +32,7 @@ def _constrain(t, spec_fn):
     GSPMD replicates ambiguous while-loop carries — without this, the
     backward's dq carry materializes at GLOBAL batch size (20 GiB/device
     for llama4-400b)."""
-    from repro.sharding.hints import current_axes
+    from repro.sharding.hints import constrain, current_axes
 
     axes = current_axes()
     if not axes:
@@ -41,10 +41,7 @@ def _constrain(t, spec_fn):
 
     dp = tuple(a for a in ("pod", "data") if a in axes) or None
     m = "model" if "model" in axes else None
-    try:
-        return jax.lax.with_sharding_constraint(t, spec_fn(P, dp, m))
-    except Exception:
-        return t
+    return constrain(t, spec_fn(P, dp, m))
 
 
 def _pin_batch(t):  # batch-major block tensors: pin batch over dp only

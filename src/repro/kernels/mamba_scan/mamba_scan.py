@@ -100,10 +100,8 @@ def mamba_scan_pallas(x, dt, A, B, C, h0=None, *, chunk: int | None = None,
             jax.ShapeDtypeStruct((b, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x32, dt32, A32, B32, C32, h0)
     return y, hf

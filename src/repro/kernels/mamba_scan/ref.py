@@ -53,7 +53,7 @@ def mamba_scan_ref(x, dt, A, B, C, h0=None, chunk: int | None = None):
         """Keep d_inner sharded over 'model' through the scan — GSPMD
         otherwise gathers every (b, chunk, d_inner, n) intermediate to
         full d_inner in f32 (275 GB/step on falcon-mamba train)."""
-        from repro.sharding.hints import current_axes
+        from repro.sharding.hints import constrain, current_axes
 
         axes = current_axes()
         if not axes or "model" not in axes:
@@ -64,10 +64,7 @@ def mamba_scan_ref(x, dt, A, B, C, h0=None, chunk: int | None = None):
         spec = [None] * t.ndim
         spec[0] = dp
         spec[d_axis] = "model"
-        try:
-            return jax.lax.with_sharding_constraint(t, P(*spec))
-        except Exception:
-            return t
+        return constrain(t, P(*spec))
 
     def chunk_step(h, inp):
         xc, dtc, Bc, Cc = inp  # (b,c,d), (b,c,d), (b,c,n), (b,c,n)
@@ -80,7 +77,8 @@ def mamba_scan_ref(x, dt, A, B, C, h0=None, chunk: int | None = None):
         return h_all[:, -1], y
 
     def _pin_xs(t):  # (nc, b, c, d): keep d_inner sharded through the
-        from repro.sharding.hints import current_axes  # reshape/transpose
+        # reshape/transpose
+        from repro.sharding.hints import constrain, current_axes
 
         axes = current_axes()
         if not axes or "model" not in axes or t.shape[-1] != d:
@@ -88,11 +86,7 @@ def mamba_scan_ref(x, dt, A, B, C, h0=None, chunk: int | None = None):
         from jax.sharding import PartitionSpec as P
 
         dp = tuple(a for a in ("pod", "data") if a in axes) or None
-        try:
-            return jax.lax.with_sharding_constraint(
-                t, P(None, dp, None, "model"))
-        except Exception:
-            return t
+        return constrain(t, P(None, dp, None, "model"))
 
     xs = (
         _pin_xs(x.reshape(b, nc, c, d).transpose(1, 0, 2, 3)),

@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 import repro.configs as cfgs
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import TARGET_KIND, make_production_mesh, peaks
 from repro.models import SHAPES, build, cell_applicable
 from repro.optim import init_opt_state, opt_state_partition_specs
 from repro.runtime.train_loop import TrainConfig, make_train_step
@@ -136,8 +136,6 @@ def analyze(lowered, *, mesh, want_hlo: bool = False) -> dict:
     compile_s = time.time() - t0
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<0.5 returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     ana = analyze_hlo(hlo)  # trip-count-weighted (cost_analysis counts
     coll = ana.collectives  # while bodies once)
@@ -155,7 +153,7 @@ def analyze(lowered, *, mesh, want_hlo: bool = False) -> dict:
             "peak_bytes_per_device": int(
                 mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes),
-            "hbm_bytes_per_device": int(HW["hbm_bytes"]),
+            "hbm_bytes_per_device": int(peaks(TARGET_KIND)["hbm_bytes"]),
         },
         # cost_analysis on the post-SPMD module is PER DEVICE and counts
         # while bodies ONCE (under-reports scanned models); the hlo_*

@@ -15,12 +15,8 @@ import jax
 
 
 def _mk(shape, axes):
-    # jax >= 0.5 takes axis_types; 0.4.x has neither the kwarg nor AxisType.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -36,10 +32,30 @@ def make_mesh(shape, axes=None):
     return _mk(tuple(shape), tuple(axes))
 
 
-HW = {
-    # TPU v5e per-chip constants used by the roofline
-    "peak_flops_bf16": 197e12,  # FLOP/s
-    "hbm_bandwidth": 819e9,  # B/s
-    "hbm_bytes": 16 * 2**30,  # 16 GiB
-    "ici_link_bandwidth": 50e9,  # B/s per link (assignment's constant)
+#: Per-chip peaks, keyed by ``jax.Device.device_kind``.  A kind missing
+#: here has no peaks: :func:`peaks` raises rather than borrow another
+#: chip's numbers.
+PEAKS = {
+    "TPU v5 lite": {  # TPU v5e
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI "
+                  "(4 links of 50 GB/s)",
+        "peak_flops_bf16": 197e12,  # FLOP/s
+        "hbm_bandwidth": 819e9,  # B/s
+        "hbm_bytes": 16 * 2**30,
+        "ici_link_bandwidth": 50e9,  # B/s per link
+    },
 }
+
+#: The chip the dry-run grid and its roofline model (``make_production_mesh``).
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Published peaks of one chip of ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to PEAKS with their "
+                       f"source") from None

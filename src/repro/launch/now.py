@@ -12,6 +12,10 @@ process death the lease/reschedule path has to absorb.
 
 Workers print their port before importing jax, so pool startup is fast;
 the first recruit blocks until the worker finishes importing (~seconds).
+Each worker process initialises JAX for itself, and an accelerator
+belongs to one process at a time: a pool refuses to start in a process
+that already holds one (run in-process ``Service``s there, one per
+device, instead).
 A ``--parent-pid`` watchdog makes workers exit if the launcher dies, so
 crashed test runs don't leak processes.
 
@@ -53,6 +57,23 @@ class NowWorker:
         return self.proc.poll() is None
 
 
+def refuse_if_holding_accelerator() -> None:
+    """Raise if this process holds an accelerator: a worker process it
+    spawns could not reach that device, and would fail or hang trying."""
+    if "jax" not in sys.modules:
+        return
+    import jax
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() and (
+            jax.default_backend() != "cpu"):
+        raise RuntimeError(
+            f"this process holds the {jax.default_backend()} devices, and "
+            f"a device belongs to one process at a time: worker processes "
+            f"could not use it.  Start the pool before this process "
+            f"touches JAX, or run in-process Services, one per device.")
+
+
 class NowPool:
     """Spawn, register, kill, and reap ``proc://`` farm workers."""
 
@@ -67,6 +88,7 @@ class NowPool:
         if transport not in ("proc", "shm"):
             raise ValueError(f"NowPool transport must be 'proc' or 'shm', "
                              f"got {transport!r}")
+        refuse_if_holding_accelerator()
         self.lookup = lookup
         self.transport = transport
         self.workers: list[NowWorker] = []

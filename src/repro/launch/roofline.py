@@ -24,7 +24,7 @@ import glob
 import json
 import os
 
-from repro.launch.mesh import HW
+from repro.launch.mesh import TARGET_KIND, peaks
 from repro.models.registry import SHAPES
 
 GiB = 2**30
@@ -154,21 +154,22 @@ def analyze_cell(rec: dict) -> dict | None:
     bytes_dev = analytic_memory_bytes(rec)
     wire_dev = rec["collectives"]["total_wire_bytes"]
     chips = rec["n_chips"]
-    compute_s = flops_dev / HW["peak_flops_bf16"]
-    memory_s = bytes_dev / HW["hbm_bandwidth"]
-    coll_s = wire_dev / HW["ici_link_bandwidth"]
+    hw = peaks(TARGET_KIND)
+    compute_s = flops_dev / hw["peak_flops_bf16"]
+    memory_s = bytes_dev / hw["hbm_bandwidth"]
+    coll_s = wire_dev / hw["ici_link_bandwidth"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dom = max(terms, key=terms.get)
     mf = model_flops(rec)
     hlo_global = flops_dev * chips
     bound = max(terms.values())
     # roofline fraction: useful work at peak / modeled step time
-    useful_s = mf / (chips * HW["peak_flops_bf16"])
+    useful_s = mf / (chips * hw["peak_flops_bf16"])
     return {
         "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
         "kind": SHAPES[rec["shape"]].kind,
         "compute_s": compute_s, "memory_s": memory_s, "collective_s": coll_s,
-        "memory_hlo_s": hlo_bytes_dev / HW["hbm_bandwidth"],
+        "memory_hlo_s": hlo_bytes_dev / hw["hbm_bandwidth"],
         "dominant": dom,
         "model_flops": mf,
         "hlo_flops_global": hlo_global,
@@ -176,7 +177,7 @@ def analyze_cell(rec: dict) -> dict | None:
         "roofline_fraction": useful_s / bound if bound else 0.0,
         "peak_gib": rec["memory"]["peak_bytes_per_device"] / GiB,
         "fits_hbm": rec["memory"]["peak_bytes_per_device"]
-        <= HW["hbm_bytes"],
+        <= hw["hbm_bytes"],
         "advice": _advice(rec, dom),
         "collective_counts": rec["collectives"]["count"],
     }

@@ -14,8 +14,10 @@ import numpy as np
 
 import repro.configs as cfgs
 from repro.core import LookupService, Service
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import build
-from repro.runtime.serve_loop import ServeConfig, serve_requests
+from repro.runtime.serve_loop import (ServeConfig, generated_tokens,
+                                     make_generate_program, serve_requests)
 
 
 def main() -> None:
@@ -34,6 +36,7 @@ def main() -> None:
                          "attention/scan dispatch picks tuned chunkings "
                          "up from it; untuned shapes keep the defaults")
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.tune_cache:
         from repro.tune import configure
@@ -48,7 +51,9 @@ def main() -> None:
     params = api.init(jax.random.PRNGKey(0))
 
     lookup = LookupService()
-    services = [Service(lookup) for _ in range(args.services)]
+    devices = jax.devices()
+    services = [Service(lookup, devices=[devices[i % len(devices)]])
+                for i in range(args.services)]
     for s in services:
         s.start()
     if args.kill_one:
@@ -60,7 +65,9 @@ def main() -> None:
                      prompt_len=args.prompt_len,
                      batch_per_task=args.batch_per_task)
     t0 = time.perf_counter()
-    gen, stats = serve_requests(api, params, prompts, sc, lookup=lookup)
+    results, stats = serve_requests(make_generate_program(api, sc, params),
+                                    prompts, sc, lookup=lookup)
+    gen = generated_tokens(results)
     dt = time.perf_counter() - t0
     toks = gen.shape[0] * gen.shape[1]
     print(f"generated {gen.shape} in {dt:.2f}s "

@@ -37,7 +37,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Sequence
 
-from .now import _PORT_PREFIX, NowPool, _watchdog
+from .now import (_PORT_PREFIX, NowPool, _watchdog,
+                  refuse_if_holding_accelerator)
 
 
 @dataclass
@@ -70,6 +71,7 @@ class TcpPool:
                  keepalive_s: float = 0.25):
         from repro.core.transport.tcp import LookupServer, RemoteLookup
 
+        refuse_if_holding_accelerator()
         if lookup_address is None:
             self.server: LookupServer | None = LookupServer(host=host)
             self.lookup_address = self.server.address

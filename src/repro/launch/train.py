@@ -22,6 +22,7 @@ import repro.configs as cfgs
 from repro.checkpoint import AsyncCheckpointer
 from repro.core import LookupService, Service
 from repro.data import make_dataset
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import build
 from repro.runtime import TrainConfig, Trainer
 from repro.runtime.local_sgd import LocalSGDConfig, LocalSGDTrainer
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = cfgs.get(args.arch)
     if args.reduced:
@@ -61,8 +63,9 @@ def main() -> None:
               f"(step {logs[-1]['step']}, {logs[-1]['step_time_s']*1e3:.0f} ms/step)")
     else:
         lookup = LookupService()
-        for _ in range(args.services):
-            Service(lookup).start()
+        devices = jax.devices()
+        for i in range(args.services):
+            Service(lookup, devices=[devices[i % len(devices)]]).start()
         ls = LocalSGDConfig(inner_steps=4, n_shards=args.services * 2,
                             batch_per_shard=args.batch,
                             seq_len=args.seq_len)

@@ -128,6 +128,7 @@ class LocalSGDTrainer:
         client = BasicClient(self.program, None, tasks, out,
                              lookup=self.lookup, lease_s=60.0)
         client.compute(timeout=timeout)
+        client.join(timeout)
         self.farm_stats.append(client.stats())
         # merge: average deltas, Nesterov outer step
         avg = jax.tree_util.tree_map(

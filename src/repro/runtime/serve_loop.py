@@ -17,6 +17,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import BasicClient, FarmExecutor, Program
 from repro.models.registry import ModelAPI
@@ -33,15 +34,15 @@ class ServeConfig:
 def make_generate_program(api: ModelAPI, sc: ServeConfig, params) -> Program:
     """payload: {"tokens": (B, prompt_len)} -> {"generated": (B, N)}.
 
-    ``params`` are closed over (weights are resident on the service; the
-    task payload is only the request batch — matching JJPF, where the
-    program ships once at recruit time and tasks stay small)."""
+    ``params`` are the program's resident state (weights are resident on
+    the service; the task payload is only the request batch — matching
+    JJPF, where the program ships once at recruit time and tasks stay
+    small).  ``program.fn(params, payload)`` is the generate function."""
     cfg = api.cfg
     budget = sc.prompt_len + sc.max_new_tokens
 
-    def generate(payload):
+    def generate(params, payload):
         tokens = payload["tokens"]
-        B = tokens.shape[0]
         logits, caches = api.prefill(params, payload, seq_budget=budget)
 
         def step(carry, i):
@@ -55,19 +56,30 @@ def make_generate_program(api: ModelAPI, sc: ServeConfig, params) -> Program:
                                     jnp.arange(sc.max_new_tokens))
         return {"generated": toks.T}  # (B, N)
 
-    return Program(generate, name=f"generate[{cfg.name}]")
+    return Program(generate, name=f"generate[{cfg.name}]", resident=params)
 
 
-def serve_requests(api: ModelAPI, params, prompts, sc: ServeConfig, *,
+def serve_requests(program: Program, prompts, sc: ServeConfig, *,
                    lookup, timeout: float = 300.0):
-    """Partition ``prompts`` (N, prompt_len) into farm tasks and run them."""
-    program = make_generate_program(api, sc, params)
+    """Partition ``prompts`` (N, prompt_len) into farm tasks and run them
+    with ``program`` (from :func:`make_generate_program`).
+
+    Returns (per-task results in prompt order, farm stats).  Each result
+    stays on the device of the service that computed it;
+    :func:`generated_tokens` gathers them on the host.  Returns only when
+    no speculative duplicate of a task still runs, so the next call (or
+    the process's exit) finds the devices free."""
     n = prompts.shape[0]
     bs = sc.batch_per_task
-    tasks = [{"tokens": jnp.asarray(prompts[i:i + bs])}
+    tasks = [{"tokens": np.asarray(prompts[i:i + bs], np.int32)}
              for i in range(0, n, bs)]
     out: list = []
     client = BasicClient(program, None, tasks, out, lookup=lookup)
     client.compute(timeout=timeout)
-    gen = jnp.concatenate([o["generated"] for o in out], axis=0)
-    return gen, client.stats()
+    client.join(timeout)
+    return out, client.stats()
+
+
+def generated_tokens(results) -> np.ndarray:
+    """(N, max_new_tokens) on the host, from results on any devices."""
+    return np.concatenate([np.asarray(r["generated"]) for r in results])

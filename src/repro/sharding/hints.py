@@ -25,15 +25,10 @@ def current_axes() -> tuple[str, ...] | None:
 
 def current_mesh():
     """The ambient physical mesh (``with mesh:``), or None."""
-    try:
-        from jax.interpreters import pxla
+    from jax._src.mesh import thread_resources
 
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    return None
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
 
 
 @contextlib.contextmanager
@@ -72,11 +67,17 @@ def spec_for(kind: str, axes, ndim: int) -> P:
     raise KeyError(kind)
 
 
+def constrain(x, spec: P):
+    """``with_sharding_constraint``, except inside a ``shard_map`` body:
+    there the mesh axes are manual, each value is one device's shard, and
+    there is no layout left to pin."""
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return x
+    return jax.lax.with_sharding_constraint(x, spec)
+
+
 def shard_hint(x, kind: str):
     axes = current_axes()
     if not axes:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, spec_for(kind, axes, x.ndim))
-    except Exception:
-        return x
+    return constrain(x, spec_for(kind, axes, x.ndim))

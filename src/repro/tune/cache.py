@@ -1,8 +1,15 @@
 """The persistent tuning cache every model path reads.
 
-Tuned configs are keyed by ``(kernel, shape-bucket, dtype, backend)``:
+Tuned configs are keyed by ``(kernel, shape-bucket, dtype, backend,
+platform)``, where backend is the kernel implementation (xla or pallas)
+and platform that of the device the winner was timed on, as the tuner
+reports it (``sim`` for the scripted cost model):
 
-    flash_fwd|B=1,D=64,Dv=64,H=8,K=2,Skv=1024,Sq=1024|float32|pallas
+    flash_fwd|B=1,D=64,Dv=64,H=8,K=2,Skv=1024,Sq=1024|float32|pallas|tpu
+
+A winner timed on one platform says nothing about another, so a lookup
+only sees entries of the platform this process computes on
+(``jax.devices()[0].platform``).
 
 Sequence and batch dims are bucketed to the next power of two, so one
 sweep at 1024 covers every prompt length in (512, 1024] — the kernels'
@@ -30,6 +37,7 @@ existed.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -61,8 +69,18 @@ def shape_bucket(shape: dict) -> str:
     return ",".join(parts)
 
 
-def cache_key(kernel: str, shape: dict, dtype: str, backend: str) -> str:
-    return f"{kernel}|{shape_bucket(shape)}|{dtype}|{backend}"
+@functools.cache
+def device_platform() -> str:
+    """The platform this process computes on: the one lookups serve."""
+    import jax
+
+    return jax.devices()[0].platform
+
+
+def cache_key(kernel: str, shape: dict, dtype: str, backend: str,
+              platform: str | None = None) -> str:
+    platform = platform or device_platform()
+    return f"{kernel}|{shape_bucket(shape)}|{dtype}|{backend}|{platform}"
 
 
 # one lock per cache file path, shared across TuningCache instances in
@@ -94,7 +112,8 @@ class TuningCache:
     # ---------------- in-memory ------------------------------------ #
     def lookup(self, kernel: str, shape: dict, dtype: str,
                backend: str) -> dict | None:
-        """The tuned record (``{"config", "us", ...}``) or None."""
+        """The tuned record (``{"config", "us", ...}``) for this
+        process's platform, or None."""
         rec = self._data.get(cache_key(kernel, shape, dtype, backend))
         if rec is None:
             self.misses += 1
@@ -103,12 +122,14 @@ class TuningCache:
         return rec
 
     def put(self, kernel: str, shape: dict, dtype: str, backend: str,
-            config: dict, us: float, *, meta: dict | None = None,
-            save: bool = True) -> str:
-        key = cache_key(kernel, shape, dtype, backend)
+            config: dict, us: float, *, platform: str,
+            meta: dict | None = None, save: bool = True) -> str:
+        """Store a winner timed on ``platform``; returns its key."""
+        key = cache_key(kernel, shape, dtype, backend, platform)
         rec = {"config": {k: int(v) for k, v in sorted(config.items())},
                "us": float(us), "kernel": kernel, "dtype": dtype,
-               "backend": backend, "tuned_at": time.time()}
+               "backend": backend, "platform": platform,
+               "tuned_at": time.time()}
         if meta:
             rec["meta"] = meta
         with self._lock:

@@ -36,6 +36,9 @@ import numpy as np
 from .space import KernelConfigError, validate_config
 
 _INF = float("inf")
+#: the platform a scripted-cost result is keyed to: a cost model times
+#: no device, so its winners must never steer dispatch on a real one
+SIM_PLATFORM = "sim"
 
 
 # --------------------------------------------------------------------- #
@@ -162,7 +165,9 @@ def build_fn(kernel: str, config: dict, *, interpret: bool = False):
     raise KernelConfigError(f"unknown kernel {kernel!r}")
 
 
-def _time_fn(fn, args, *, reps: int, warmup: int = 1) -> float:
+def _time_fn(fn, args, *, reps: int, warmup: int = 1) -> tuple[float, str]:
+    """Best-of-``reps`` microseconds, and the platform of the device the
+    timed call ran on."""
     import jax
 
     jax.block_until_ready(fn(*args))  # compile
@@ -174,14 +179,17 @@ def _time_fn(fn, args, *, reps: int, warmup: int = 1) -> float:
         out = fn(*args)
         jax.block_until_ready(out)
         best = min(best, time.perf_counter() - t0)
-    return best * 1e6
+    (device, *_) = jax.tree.leaves(out)[0].devices()
+    return best * 1e6, device.platform
 
 
 def measure_candidate(payload: dict) -> dict:
     """The farm task body.  Payload keys: ``kernel``, ``shape``,
     ``dtype``, ``config``, ``reps``, ``seed``, optional ``cost_model``
-    ("scripted") and ``interpret``.  Never raises for a bad candidate —
-    returns ``ok=False`` with infinite cost instead."""
+    ("scripted") and ``interpret``.  A timed result names the
+    ``platform`` it was timed on (:data:`SIM_PLATFORM` for the cost
+    model).  Never raises for a bad candidate — returns ``ok=False`` with
+    infinite cost instead."""
     kernel = payload["kernel"]
     shape = payload["shape"]
     config = payload["config"]
@@ -190,13 +198,16 @@ def measure_candidate(payload: dict) -> dict:
         validate_config(kernel, shape, config)
         if payload.get("cost_model") == "scripted":
             us = scripted_cost_us(kernel, shape, config, seed=seed)
+            platform = SIM_PLATFORM
         else:
             fn = build_fn(kernel, config,
                           interpret=bool(payload.get("interpret", False)))
             args = make_inputs(kernel, shape, payload.get("dtype", "float32"),
                                seed)
-            us = _time_fn(fn, args, reps=int(payload.get("reps", 3)))
-        return {"ok": True, "us": float(us), "config": config}
+            us, platform = _time_fn(fn, args,
+                                    reps=int(payload.get("reps", 3)))
+        return {"ok": True, "us": float(us), "config": config,
+                "platform": platform}
     except Exception as e:  # a bad candidate fails the TASK, not the worker
         return {"ok": False, "us": _INF, "config": config,
                 "error": f"{type(e).__name__}: {e}"}

@@ -24,7 +24,9 @@ winners no matter how the virtual services race.
 
 Results land in the :class:`~repro.tune.cache.TuningCache`, which kernel
 dispatch reads — tuning here makes ``serve_loop``/``train_loop``/the
-benchmarks faster with zero call-site changes.
+benchmarks faster with zero call-site changes.  A winner is keyed by the
+platform its candidates were timed on; a scripted sweep's is keyed
+``sim`` and steers no dispatch on a real device.
 """
 
 from __future__ import annotations
@@ -160,6 +162,7 @@ class KernelTuner:
             self.obs.event("tune-sweep", None, kernel, len(cands), pruned)
 
         survivors = cands
+        platforms: set[str] = set()  # where the candidates were timed
         rounds: list[tuple[int, int]] = []
         failed = 0
         reps = base_reps
@@ -179,8 +182,10 @@ class KernelTuner:
                     pass
             else:
                 pool = survivors
-            timed = self._measure_round(kernel, shape, dtype, pool, reps,
-                                        seed, cost_model, interpret, rnd)
+            timed, seen = self._measure_round(kernel, shape, dtype, pool,
+                                              reps, seed, cost_model,
+                                              interpret, rnd)
+            platforms |= seen
             failed += sum(1 for us, _ in timed if not math.isfinite(us))
             rounds.append((len(pool), reps))
             if last:
@@ -208,8 +213,13 @@ class KernelTuner:
         if self.obs is not None:
             self.obs.event("tune-winner", None, kernel,
                            tuple(sorted(winner.items())), round(win_us, 3))
-        if self.cache is not None:
+        if len(platforms) > 1:
+            raise ValueError(f"{kernel}: candidates were timed on more than "
+                             f"one platform {sorted(platforms)}; tune each "
+                             f"on a farm of one platform")
+        if self.cache is not None and platforms:
             self.cache.put(kernel, shape, dtype, backend, winner, win_us,
+                           platform=platforms.pop(),
                            meta={"speedup": round(result.speedup, 4),
                                  "seed": seed,
                                  "cost_model": cost_model or "measured"},
@@ -219,7 +229,8 @@ class KernelTuner:
     def _measure_round(self, kernel, shape, dtype, configs, reps, seed,
                        cost_model, interpret, rnd):
         """Submit one round as a farm job; returns [(us, config)] aligned
-        to ``configs`` (results_in_order ⇒ task id == candidate index)."""
+        to ``configs`` (results_in_order ⇒ task id == candidate index),
+        and the platforms the timed candidates ran on."""
         payloads = [{"kernel": kernel, "shape": dict(shape), "dtype": dtype,
                      "config": dict(cfg), "reps": int(reps),
                      "seed": int(seed), "interpret": bool(interpret),
@@ -230,10 +241,12 @@ class KernelTuner:
                            int(reps))
         job = self.scheduler.submit(self.program, payloads,
                                     name=f"tune-{kernel}-r{rnd}")
-        out = []
+        out, platforms = [], set()
         for cfg, res in zip(configs, job.results_in_order()):
             us = float(res["us"]) if res.get("ok") else float("inf")
             out.append((us, cfg))
+            if res.get("ok"):
+                platforms.add(res["platform"])
             if self.obs is not None:
                 self._m_timed.inc()
                 if not res.get("ok"):
@@ -241,7 +254,7 @@ class KernelTuner:
                     self.obs.event("tune-candidate-failed", None, kernel,
                                    tuple(sorted(cfg.items())),
                                    res.get("error", ""))
-        return out
+        return out, platforms
 
     def tune_all(self, specs, **kw) -> list[TuneResult]:
         """Sweep a list of ``(kernel, shape)`` (or ``(kernel, shape,

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.utils.hlo import analyze_hlo, _parse_computations
 
@@ -70,3 +71,13 @@ def test_parse_computations_finds_entry():
     entry, comps = _parse_computations(t)
     assert entry is not None
     assert entry in comps
+
+
+def test_peaks_keyed_by_device_kind_and_unknown_kind_raises():
+    from repro.launch.mesh import TARGET_KIND, peaks
+
+    v5e = peaks(TARGET_KIND)
+    assert v5e["peak_flops_bf16"] == 197e12 and v5e["hbm_bandwidth"] == 819e9
+    assert "Google Cloud" in v5e["source"]
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")

@@ -94,3 +94,30 @@ def test_hints_noop_without_mesh_context():
 
     x = jnp.ones((4, 8, 16))
     assert shard_hint(x, "activations") is x
+
+
+def test_hints_pin_outside_shard_map_and_step_aside_inside():
+    """Inside a shard_map body the mesh axes are manual: a hint there has
+    nothing to pin and must not raise (it used to be swallowed by a
+    catch-all); outside, the constraint is applied."""
+    from repro.launch.mesh import make_mesh
+    from repro.sharding.hints import mesh_axes, shard_hint
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    spec = P("data", "model", None)
+
+    def body(x):
+        assert shard_hint(x, "activations") is x
+        return x * 2
+
+    @jax.jit
+    def f(x):
+        x = shard_hint(x, "activations")
+        return jax.shard_map(body, mesh=mesh, in_specs=spec,
+                             out_specs=spec)(x)
+
+    with mesh, mesh_axes(mesh.axis_names):
+        text = f.lower(jnp.ones((2, 8, 16))).as_text()
+        out = f(jnp.ones((2, 8, 16)))
+    assert "sharding_constraint" in text
+    assert float(out.sum()) == 2 * 2 * 8 * 16

@@ -30,6 +30,32 @@ def test_two_line_api(cluster):
     assert [int(v) for v in out] == [2 * i + 1 for i in range(30)]
 
 
+def test_join_waits_out_trailing_duplicate():
+    # the slow service's copy of its task is overtaken by a speculative
+    # duplicate on the fast one, so compute() returns while it still runs
+    lookup = LookupService()
+    fast = Service(lookup, service_id="fast")
+    slow = Service(lookup, service_id="slow", task_delay_s=1.0)
+    for s in (fast, slow):
+        s.start()
+
+    def inc(x):
+        time.sleep(0.02)  # both services are leasing before the queue ends
+        return x + 1
+
+    out = []
+    cm = BasicClient(Program(inc, jit=False), None, list(range(20)), out,
+                     lookup=lookup)
+    cm.compute(timeout=60)
+    assert out == [i + 1 for i in range(20)]
+    assert cm.stats()["speculative_issues"] == 1
+    assert slow.tasks_executed == 0  # still asleep inside its task
+    cm.join(timeout=10)
+    assert slow.tasks_executed == 1
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("farm-slow")]
+
+
 def test_skeleton_composition_runs_normalized(cluster):
     lookup, _ = cluster
     skel = Pipe(Farm(Seq(Program(lambda x: x + 1, name="inc"))),

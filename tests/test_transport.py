@@ -9,6 +9,7 @@ import struct
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -53,6 +54,41 @@ def test_program_ships_and_still_computes():
     q = load_program(dump_program(p))
     assert q.name == "tri"
     assert float(q(jnp.asarray(2.0))) == 6.0
+
+
+def test_program_resident_state_ships_and_is_an_argument():
+    p = Program(lambda w, x: x * w, name="scale", resident=jnp.asarray(3.0))
+    q = load_program(dump_program(p))
+    assert float(q(jnp.asarray(2.0))) == 6.0
+    # the resident state is passed to the executable, not baked into it
+    fn = q.prepare([jax.devices()[0]])
+    assert float(fn(jnp.asarray(2.0))) == 6.0
+    assert "constant(3" not in jax.jit(q.fn).lower(
+        q.resident, jnp.asarray(2.0)).as_text()
+    assert float(q.prepare_batched()(jnp.ones(4))[0]) == 3.0
+
+
+def test_composed_program_keeps_stage_resident_state_an_argument():
+    from repro.core import compose_programs
+
+    scale = Program(lambda w, x: x * w, name="scale",
+                    resident=jnp.asarray(3.0))
+    fused = compose_programs([Program(lambda x: x + 1.0, name="inc"), scale])
+    assert fused.resident[1] is scale.resident
+    assert float(fused(jnp.asarray(2.0))) == 9.0
+    assert float(fused.prepare([jax.devices()[0]])(jnp.asarray(2.0))) == 9.0
+    assert "constant(3" not in jax.jit(fused.fn).lower(
+        fused.resident, jnp.asarray(2.0)).as_text()
+    assert fused.prepare_batched()(jnp.ones(4)).tolist() == [6.0] * 4
+
+
+def test_pool_refuses_a_parent_that_holds_the_accelerator(monkeypatch):
+    from jax._src import xla_bridge
+
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        NowPool(1)
 
 
 # --------------------------------------------------------------------- #

@@ -39,6 +39,10 @@ from repro.tune import (DEFAULTS, KERNELS, KernelConfigError, KernelTuner,
                         measure_candidate, resolve_block, resolve_config,
                         scripted_cost_us, search_space, set_cache,
                         shape_bucket, validate_config)
+from repro.tune.cache import device_platform
+
+#: the platform this process computes on, which lookups serve
+HERE = device_platform()
 
 SHAPES = {
     "flash_fwd": {"B": 1, "Sq": 1024, "Skv": 1024, "H": 8, "K": 2, "D": 64,
@@ -117,7 +121,7 @@ def test_cache_roundtrip(tmp_path):
     shape = SHAPES["xla_flash"]
     c = TuningCache(path)
     key = c.put("xla_flash", shape, "float32", "xla",
-                {"q_chunk": 128, "kv_chunk": 256}, 123.4,
+                {"q_chunk": 128, "kv_chunk": 256}, 123.4, platform=HERE,
                 meta={"speedup": 2.0})
     reloaded = TuningCache(path)
     rec = reloaded.lookup("xla_flash", shape, "float32", "xla")
@@ -125,6 +129,26 @@ def test_cache_roundtrip(tmp_path):
     assert rec["us"] == 123.4
     assert rec["meta"]["speedup"] == 2.0
     assert key in json.load(open(path))["entries"]
+
+
+def _on_platform(monkeypatch, platform):
+    """Make lookups behave as in a process computing on ``platform``."""
+    monkeypatch.setattr("repro.tune.cache.device_platform",
+                        lambda: platform)
+
+
+def test_cache_entry_serves_only_its_platform(tmp_path, monkeypatch):
+    # a winner timed on the CPU must not steer dispatch on the chip
+    shape = SHAPES["xla_flash"]
+    c = TuningCache(str(tmp_path / "tune.json"))
+    c.put("xla_flash", shape, "float32", "xla", {"q_chunk": 64}, 1.0,
+          platform="cpu")
+    _on_platform(monkeypatch, "tpu")
+    assert c.lookup("xla_flash", shape, "float32", "xla") is None
+    assert cache_key("xla_flash", shape, "float32", "xla").endswith("|tpu")
+    _on_platform(monkeypatch, "cpu")
+    assert c.lookup("xla_flash", shape, "float32", "xla")["platform"] == "cpu"
+    assert cache_key("xla_flash", shape, "float32", "xla").endswith("|cpu")
 
 
 def test_shape_bucketing():
@@ -145,7 +169,7 @@ def test_shape_bucketing():
 def test_cache_bucketed_lookup_covers_nearby_shapes(tmp_path):
     c = TuningCache(str(tmp_path / "tune.json"))
     c.put("xla_flash", {"B": 1, "Sq": 1024, "D": 64}, "float32", "xla",
-          {"q_chunk": 128}, 1.0)
+          {"q_chunk": 128}, 1.0, platform=HERE)
     # a sweep at 1024 serves a 1000-token prompt (same bucket)...
     assert c.lookup("xla_flash", {"B": 1, "Sq": 1000, "D": 64}, "float32",
                     "xla") is not None
@@ -162,7 +186,7 @@ def test_concurrent_cache_writes_lose_nothing(tmp_path):
         # D is exact in the key (not pow2-bucketed) — 16 distinct keys
         c = TuningCache(path)
         c.put("xla_flash", {"Sq": 1024, "D": 8 * (i + 1)}, "float32", "xla",
-              {"q_chunk": 64}, float(i))
+              {"q_chunk": 64}, float(i), platform=HERE)
 
     threads = [threading.Thread(target=writer, args=(i,)) for i in range(n)]
     for t in threads:
@@ -185,7 +209,8 @@ def test_best_config_fallback_and_memo(tmp_path):
     # cache miss: default, memoized
     assert best_config("xla_flash", shape, "float32", "xla",
                        default) == default
-    c.put("xla_flash", shape, "float32", "xla", {"q_chunk": 64}, 1.0)
+    c.put("xla_flash", shape, "float32", "xla", {"q_chunk": 64}, 1.0,
+          platform=HERE)
     # generation bump invalidates the memo; partial entries merge over
     # the default
     cfg = best_config("xla_flash", shape, "float32", "xla", default)
@@ -307,7 +332,7 @@ def test_dispatch_through_tuned_config_matches_reference(tmp_path):
     v = jax.random.normal(kv, (B, S, K, D), jnp.float32)
     shape = {"B": B, "Sq": S, "Skv": S, "H": H, "K": K, "D": D, "Dv": D}
     cache.put("xla_flash", shape, "float32", "xla",
-              {"q_chunk": 64, "kv_chunk": 128}, 1.0)
+              {"q_chunk": 64, "kv_chunk": 128}, 1.0, platform=HERE)
     out = flash_attention_dispatch(q, k, v, causal=True)
     ref = attention_naive(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
@@ -321,7 +346,7 @@ def test_dispatch_through_tuned_config_matches_reference(tmp_path):
     Bm = jax.random.normal(kb2, (b, s, n))
     C = jax.random.normal(kc2, (b, s, n))
     cache.put("mamba", {"b": b, "s": s, "d": d, "n": n}, "float32", "xla",
-              {"chunk": 32, "block_d": 8}, 1.0)
+              {"chunk": 32, "block_d": 8}, 1.0, platform=HERE)
     y, h = mamba_scan_dispatch(x, dt, A, Bm, C)
     y_ref, h_ref = mamba_scan_naive(x, dt, A, Bm, C)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-4)
@@ -362,7 +387,8 @@ def test_sim_sweep_same_seed_identical_winner_and_trace():
     assert r1.config == expect
 
 
-def test_sim_sweep_caches_winner_and_dispatch_reads_it(tmp_path):
+def test_sim_sweep_caches_winner_and_dispatch_reads_it(tmp_path,
+                                                       monkeypatch):
     path = str(tmp_path / "tune.json")
     with SimCluster(speed_factors=[1, 1], seed=5) as cluster:
         with cluster.make_scheduler(max_batch=4) as sched:
@@ -370,12 +396,37 @@ def test_sim_sweep_caches_winner_and_dispatch_reads_it(tmp_path):
             r = tuner.tune("xla_flash", SIM_SHAPE, cost_model="scripted",
                            seed=3)
     assert r.speedup > 0 and r.failed == 0
-    # fresh process-equivalent: reload from disk, dispatch must read it
+    # fresh process-equivalent: reload from disk; dispatch in a process
+    # on the platform the winner was timed on (the cost model's) reads it
     reloaded = TuningCache(path)
     set_cache(reloaded)
+    _on_platform(monkeypatch, "sim")
     got = best_config("xla_flash", SIM_SHAPE, "float32", "xla",
                       DEFAULTS["xla_flash"])
     assert {k: got[k] for k in r.config} == r.config
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_sim_sweep_not_served_to_a_device_lookup(platform, monkeypatch):
+    # the scripted cost model times no device: its winner steers no
+    # dispatch on one, whatever platform the tuning process sat on
+    cache = TuningCache()
+    with SimCluster(speed_factors=[1, 1], seed=5) as cluster:
+        with cluster.make_scheduler(max_batch=4) as sched:
+            KernelTuner(scheduler=sched, cache=cache).tune(
+                "xla_flash", SIM_SHAPE, cost_model="scripted", seed=3)
+    assert [k.rsplit("|", 1)[1] for k in cache.entries()] == ["sim"]
+    _on_platform(monkeypatch, platform)
+    assert cache.lookup("xla_flash", SIM_SHAPE, "float32", "xla") is None
+
+
+def test_measured_winner_is_keyed_to_the_device_that_timed_it():
+    res = measure_candidate({"kernel": "xla_flash",
+                             "shape": {"B": 1, "Sq": 128, "Skv": 128, "H": 2,
+                                       "K": 1, "D": 16, "Dv": 16},
+                             "config": {"q_chunk": 64, "kv_chunk": 64},
+                             "reps": 1})
+    assert res["ok"] and res["platform"] == jax.devices()[0].platform
 
 
 def test_tuner_bad_candidates_fail_tasks_not_workers():
@@ -384,10 +435,11 @@ def test_tuner_bad_candidates_fail_tasks_not_workers():
     with SimCluster(speed_factors=[1, 1], seed=5) as cluster:
         with cluster.make_scheduler(max_batch=4) as sched:
             tuner = KernelTuner(scheduler=sched, cache=TuningCache())
-            timed = tuner._measure_round(
+            timed, platforms = tuner._measure_round(
                 "xla_flash", SIM_SHAPE, "float32",
                 [{"q_chunk": 333, "kv_chunk": 128},   # invalid
                  {"q_chunk": 128, "kv_chunk": 128}],  # valid
                 1, 0, "scripted", False, 0)
     assert timed[0][0] == float("inf")
     assert np.isfinite(timed[1][0])
+    assert platforms == {"sim"}
