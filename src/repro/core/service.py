@@ -25,6 +25,7 @@ constructed with ``lookup=None`` (registration is the launcher's job).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Any, Callable
@@ -37,6 +38,14 @@ from .discovery import LookupService, ServiceDescriptor, new_service_id
 from .errors import ServiceFailure  # noqa: F401  (re-exported: old import path)
 from .skeletons import Program
 from .transport.inproc import register_local
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _span(obs, kind: str, *fields):
+    """``obs.span(kind, *fields)``, or a shared no-op without ``obs``:
+    tracing off constructs nothing."""
+    return _NO_SPAN if obs is None else obs.span(kind, *fields)
 
 
 class Service:
@@ -79,6 +88,9 @@ class Service:
         self.cache_hits = 0
         self.cache_misses = 0
         self.last_heartbeat = time.monotonic()
+        # the recruiting client's repro.obs bundle, set by its in-process
+        # handle while recruited; None: no spans
+        self.obs = None
 
     # ---------------- lifecycle (Algorithm 2) ------------------------ #
     def start(self) -> None:
@@ -176,13 +188,15 @@ class Service:
 
     def execute(self, program: Program, payload) -> Any:
         """Run one task.  Raises ServiceFailure if the node is dead or its
-        fault-injection counter fires."""
+        fault-injection counter fires.  With ``self.obs`` set, the
+        compiled call is a ``launch`` span."""
         with self._lock:
             self._check_dispatchable()
         fn = self._get_compiled(program, payload, None)
         if self.task_delay_s:
             time.sleep(self.task_delay_s)  # network/serialization stand-in
-        result = fn(payload)
+        with _span(self.obs, "launch", self.service_id, 1):
+            result = fn(payload)
         result = jax.block_until_ready(result)
         if self.speed_factor != 1.0:
             # heterogeneity simulation: slower nodes take proportionally longer
@@ -204,7 +218,11 @@ class Service:
         The dispatch round-trip stand-in (``task_delay_s``) is paid once
         per batch — that is the point of batching — while the
         heterogeneity stand-in (``speed_factor``) scales with the number
-        of tasks, like real compute would."""
+        of tasks, like real compute would.
+
+        With ``self.obs`` set, the host work of a jitted batch is timed
+        as spans: ``stack`` (stacking and padding), ``launch`` (the
+        compiled call) and ``unstack``."""
         n = len(payloads)
         if n == 0:
             return []
@@ -218,11 +236,15 @@ class Service:
         else:
             m = pad_to if pad_to is not None and pad_to > n else n
             fn = self._get_compiled(program, payloads[0], m)
-            stacked = pad_stacked(stack_payloads(payloads), n, m)
-            out = fn(stacked)
+            sid, obs = self.service_id, self.obs
+            with _span(obs, "stack", sid, n):
+                stacked = pad_stacked(stack_payloads(payloads), n, m)
+            with _span(obs, "launch", sid, n):
+                out = fn(stacked)
             if block:
                 out = jax.block_until_ready(out)
-            results = unstack_results(out, n)  # padding rows dropped
+            with _span(obs, "unstack", sid, n):
+                results = unstack_results(out, n)  # padding rows dropped
         if self.speed_factor != 1.0:
             time.sleep(max(0.0, (self.speed_factor - 1.0)) * 0.002 * n)
         self._finish_tasks(n)

@@ -41,7 +41,8 @@ class ServiceHandle(abc.ABC):
     needs_heartbeat: bool = False
     #: optional :class:`repro.obs.Observability` bundle — stamped by the
     #: recruiting :class:`~repro.core.pool.ServicePool` so transports can
-    #: record frame/reconnect/shm-ring events; ``None`` = no telemetry.
+    #: record frame/reconnect/shm-ring events (and an in-process service
+    #: its stack/launch/unstack spans); ``None`` = no telemetry.
     obs = None
 
     service_id: str

@@ -56,9 +56,13 @@ class InProcHandle(ServiceHandle):
         self.capabilities = dict(service.capabilities)
 
     def recruit(self, client_id: str) -> bool:
-        return self._service.recruit(client_id)
+        if not self._service.recruit(client_id):
+            return False
+        self._service.obs = self.obs  # the service's spans go to our obs
+        return True
 
     def release(self) -> None:
+        self._service.obs = None
         self._service.release()
 
     def prepare(self, program) -> None:

@@ -15,6 +15,12 @@ below (repository, control threads, pool, transports) picks it up, and
 (the default) is free: not a single event object is constructed on the
 dispatch path.
 
+:meth:`Observability.span` times a stretch of host work: one event
+``(t_end, kind, *fields, t_start)`` in the recorder, and a
+``jax.profiler.TraceAnnotation`` of the same name that carries
+``t_start``, so a profile's host plane holds the recorder's clock beside
+its own and every recorder event can be placed on the device timeline.
+
 Under ``sim://`` the whole pipeline is deterministic: same seed ⇒
 byte-identical exported traces (gated in ``tests/test_obs.py``), which
 supersedes the bespoke ``on_lease`` assignment-trace hook (still
@@ -23,6 +29,8 @@ recorder — see ``benchmarks/scale.py`` / ``heterogeneous_now.py``).
 """
 
 from __future__ import annotations
+
+from jax.profiler import TraceAnnotation
 
 from .export import (PeriodicMetricsDump, chrome_trace_events,
                      dump_metrics_jsonl, export_chrome_trace, farm_top,
@@ -78,6 +86,13 @@ class Observability:
     def events(self) -> list[tuple]:
         return self.recorder.events()
 
+    def span(self, kind: str, *fields) -> "_Span":
+        """Context manager over one stretch of host work: on a clean exit
+        it records ``(t_end, kind, *fields, t_start)`` (the convention
+        of ``drain``); meanwhile a profiler session sees a host span
+        ``kind`` with the argument ``t_start``."""
+        return _Span(self.recorder, kind, fields)
+
     def export_chrome_trace(self, path: str, **kw) -> list[dict]:
         return export_chrome_trace(self.recorder, path, **kw)
 
@@ -88,3 +103,22 @@ class Observability:
 
     def stats(self) -> dict:
         return self.recorder.stats()
+
+
+class _Span:
+    __slots__ = ("_recorder", "_kind", "_fields", "_t0", "_note")
+
+    def __init__(self, recorder: TraceRecorder, kind: str, fields: tuple):
+        self._recorder, self._kind, self._fields = recorder, kind, fields
+
+    def __enter__(self) -> "_Span":
+        self._t0 = t0 = self._recorder.clock.monotonic()
+        self._note = TraceAnnotation(self._kind, t_start=t0)
+        self._note.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._note.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            self._recorder.event(self._kind, None, *self._fields, self._t0)
+        return False

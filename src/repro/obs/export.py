@@ -9,7 +9,9 @@ https://ui.perfetto.dev and chrome://tracing load directly.  Layout:
   service's track covering its **lease** (lease start → completion) —
   the paper's per-task service time.  Each drained batch becomes a
   nested ``dispatch`` span (dispatch → materialization), so leases
-  visually contain the batches that executed them.
+  visually contain the batches that executed them; an in-process
+  service's ``stack`` / ``launch`` / ``unstack`` spans nest inside the
+  dispatch, named as on the profiler's host plane.
 * Everything else (lease grants, speculation, expiry, recruit/assign/
   revoke/rebalance, job lifecycle, transport frames) is an instant
   (``ph="i"``), and a cumulative ``tasks_done`` counter track
@@ -78,6 +80,11 @@ def chrome_trace_events(events: Iterable[tuple], *,
                         "ph": "X", "pid": 1, "tid": track(sid),
                         "ts": _us(t0), "dur": _us(t - t0),
                         "args": {"n": n, "service": sid}})
+        elif kind in ("stack", "launch", "unstack"):
+            sid, n, t0 = ev[2], ev[3], ev[-1]
+            out.append({"name": kind, "cat": kind, "ph": "X", "pid": 1,
+                        "tid": track(sid), "ts": _us(t0),
+                        "dur": _us(t - t0), "args": {"n": n, "service": sid}})
         elif kind == "lease":
             sid, pairs = ev[2], ev[3]
             instant(t, kind, sid,

@@ -38,6 +38,15 @@ EVENT_KINDS = {
     "dispatch": ("service_id, n", "ControlThread (batch handed to service)"),
     "drain": ("service_id, n, t_dispatch",
               "ControlThread (batch materialized; span = t_dispatch..t)"),
+    # service (in-process Service, through the handle's obs; spans
+    # recorded by Observability.span, t_start..t)
+    "stack": ("service_id, n, t_start",
+              "Service.execute_batch (stack_payloads + pad_stacked)"),
+    "launch": ("service_id, n, t_start",
+               "Service.execute / execute_batch (the compiled call; "
+               "results not yet materialized)"),
+    "unstack": ("service_id, n, t_start",
+                "Service.execute_batch (unstack_results)"),
     # scheduler
     "recruit": ("service_id, speed_factor", "FarmScheduler pool join"),
     "service-dead": ("service_id", "FarmScheduler (liveness verdict)"),

@@ -43,7 +43,9 @@ def make_generate_program(api: ModelAPI, sc: ServeConfig, params) -> Program:
 
     def generate(params, payload):
         tokens = payload["tokens"]
-        logits, caches = api.prefill(params, payload, seq_budget=budget)
+        # the scopes name the phases' ops in the device trace
+        with jax.named_scope("prefill"):
+            logits, caches = api.prefill(params, payload, seq_budget=budget)
 
         def step(carry, i):
             logits, caches = carry
@@ -52,8 +54,9 @@ def make_generate_program(api: ModelAPI, sc: ServeConfig, params) -> Program:
             logits, caches = api.decode(params, batch, caches)
             return (logits, caches), nxt[:, 0]
 
-        (_, _), toks = jax.lax.scan(step, (logits, caches),
-                                    jnp.arange(sc.max_new_tokens))
+        with jax.named_scope("decode"):
+            (_, _), toks = jax.lax.scan(step, (logits, caches),
+                                        jnp.arange(sc.max_new_tokens))
         return {"generated": toks.T}  # (B, N)
 
     return Program(generate, name=f"generate[{cfg.name}]", resident=params)
