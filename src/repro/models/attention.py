@@ -15,6 +15,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import custom_batching
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .common import ModelConfig
 from .layers import apply_rope, dense_init, rms_norm_headwise
@@ -237,10 +239,64 @@ def apply_attention_prefill(p, x, cfg: ModelConfig, *, window=None):
     return out @ p["wo"].astype(cfg.dtype), {"k": k, "v": v}
 
 
+@custom_batching.custom_vmap
+def update_slice(operand, update, *start):
+    """``lax.dynamic_update_slice``.  Batched with the same ``start`` for
+    every member (decode under the service's vmap writes each task's row at
+    one layer and position), it stays a dynamic-update-slice of the batched
+    operand, where JAX's own rule makes a scatter that XLA guards with a
+    bounds check and a select on every write."""
+    return jax.lax.dynamic_update_slice(operand, update, start)
+
+
+@update_slice.def_vmap
+def _update_slice_vmap(axis_size, in_batched, operand, update, *start):
+    operand_b, update_b, *start_b = in_batched
+    if any(start_b):  # a start per member: JAX's own rule
+        out = jax.vmap(update_slice.fun,
+                       in_axes=(0 if operand_b else None,
+                                0 if update_b else None,
+                                *(0 if b else None for b in start_b)))(
+            operand, update, *start)
+        return out, True
+    if not operand_b:
+        operand = jnp.broadcast_to(operand, (axis_size,) + operand.shape)
+    if not update_b:
+        update = jnp.broadcast_to(update, (axis_size,) + update.shape)
+    return update_slice(operand, update, 0, *start), True
+
+
+def write_row(cache, row, cache_index, layer=None):
+    """Writes one token's ``row`` (B, 1, ...) at position ``cache_index`` of
+    ``cache``: one layer's (B, S, ...) cache, or with ``layer`` the stacked
+    (L, B, S, ...) cache, in place of a copy of that layer's slice.
+    Returns (the written cache, that layer's (B, S, ...) view of it).
+
+    On the TPU the stack keeps the sequence axis next to the minor one, the
+    layout decode attention reads; left to itself, XLA lays the stack out
+    for the row write (batch on the sublanes), and then every layer's
+    attention re-tiles its whole slice.  The CPU keeps its default layout,
+    which any other would make it copy."""
+    row = row.astype(cache.dtype)
+    tail = (0,) * (row.ndim - 2)
+    if layer is None:
+        cache = update_slice(cache, row, 0, cache_index, *tail)
+        return cache, cache
+    cache = update_slice(cache, row[None], layer, 0, cache_index, *tail)
+    n = cache.ndim
+    seq_second_minor = Layout((0, 1, *range(3, n - 1), 2, n - 1))
+    cache = jax.lax.platform_dependent(
+        cache, tpu=lambda c: with_layout_constraint(c, seq_second_minor),
+        default=lambda c: c)
+    return cache, jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
+
+
 def apply_attention_decode(p, x, cache, cfg: ModelConfig, *, cache_index,
-                           window=None, kv_cross=None, use_rope=True):
-    """One-token decode. x: (B,1,d). cache: {"k","v"} (B,S,K,hd); the new
-    token's k/v are written at ``cache_index``. Returns (out, new_cache)."""
+                           layer=None, window=None, kv_cross=None,
+                           use_rope=True):
+    """One-token decode. x: (B,1,d). cache: {"k","v"}, one layer's (B,S,K,hd)
+    or, with ``layer``, the stacked (L,B,S,K,hd); the new token's k/v are
+    written at ``cache_index`` (of ``layer``). Returns (out, new_cache)."""
     B = x.shape[0]
     if kv_cross is not None:  # cross-attention: cache is the encoder's kv
         dt = cfg.dtype
@@ -253,19 +309,15 @@ def apply_attention_decode(p, x, cache, cfg: ModelConfig, *, cache_index,
 
     positions = jnp.full((1,), cache_index, dtype=jnp.int32) if use_rope else None
     q, k, v = _project_qkv(p, x, cfg, positions)
-    k_cache = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, cache_index, 0, 0)
-    )
-    v_cache = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, cache_index, 0, 0)
-    )
+    k_all, k_cache = write_row(cache["k"], k, cache_index, layer)
+    v_all, v_cache = write_row(cache["v"], v, cache_index, layer)
     from repro.kernels import decode_attention_dispatch
 
     out = decode_attention_dispatch(
         q, k_cache, v_cache, cache_index=cache_index, window=window
     )
     out = out.reshape(B, 1, cfg.n_heads * cfg.head_dim)
-    return out @ p["wo"].astype(cfg.dtype), {"k": k_cache, "v": v_cache}
+    return out @ p["wo"].astype(cfg.dtype), {"k": k_all, "v": v_all}
 
 
 def make_empty_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=None):

@@ -136,21 +136,32 @@ def apply_block_prefill(p, x, cfg: ModelConfig, spec: BlockSpec, *,
 
 
 def apply_block_decode(p, x, cache, cfg: ModelConfig, spec: BlockSpec, *,
-                       cache_index, long_context=False):
+                       layer, cache_index, long_context=False):
+    """One token through one block. ``cache``: this pattern position's
+    stacked cache (n_repeats leading axis); only ``layer``'s new row
+    (attention) or state (mamba) is written into it."""
     if spec.mixer == "attn":
         h = apply_norm(p["mixer_norm"], x, cfg)
         if cfg.attention == "mla":
             h, kv = mla_mod.apply_mla_decode(p["attn"], h, cache["attn"], cfg,
-                                             cache_index=cache_index)
+                                             cache_index=cache_index,
+                                             layer=layer)
         else:
             h, kv = attn_mod.apply_attention_decode(
                 p["attn"], h, cache["attn"], cfg, cache_index=cache_index,
-                window=_window_for(cfg, spec, long_context))
+                layer=layer, window=_window_for(cfg, spec, long_context))
         cache = dict(cache, attn=kv)
         x = x + h
     elif spec.mixer == "mamba":
         h = apply_norm(p["mixer_norm"], x, cfg)
-        h, st = mamba_mod.apply_mamba_decode(p["mamba"], h, cache["mamba"], cfg)
+        st = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+            cache["mamba"])
+        h, st = mamba_mod.apply_mamba_decode(p["mamba"], h, st, cfg)
+        st = jax.tree_util.tree_map(
+            lambda a, s: attn_mod.update_slice(
+                a, s[None].astype(a.dtype), layer, *(0,) * s.ndim),
+            cache["mamba"], st)
         cache = dict(cache, mamba=st)
         x = x + h
     if spec.mlp == "dense":
@@ -222,15 +233,20 @@ def apply_blocks_prefill(params, x, cfg: ModelConfig, *, seq_budget,
 
 def apply_blocks_decode(params, x, caches, cfg: ModelConfig, *, cache_index,
                         long_context=False):
-    def body(x, inp):
-        layer_params, layer_cache = inp
-        new_cache = {}
+    """The layer scan carries the stacked caches and each layer writes only
+    its new token's row, so a step moves no whole cache slice (the stack as
+    the scan's ``xs``/``ys`` would be read and written whole every step)."""
+    def body(carry, inp):
+        x, caches = carry
+        layer_params, layer = inp
+        caches = dict(caches)
         for i, spec in enumerate(cfg.pattern):
-            x, c = apply_block_decode(layer_params[f"b{i}"], x,
-                                      layer_cache[f"b{i}"], cfg, spec,
-                                      cache_index=cache_index,
-                                      long_context=long_context)
-            new_cache[f"b{i}"] = c
-        return x, new_cache
+            x, caches[f"b{i}"] = apply_block_decode(
+                layer_params[f"b{i}"], x, caches[f"b{i}"], cfg, spec,
+                layer=layer, cache_index=cache_index,
+                long_context=long_context)
+        return (x, caches), None
 
-    return jax.lax.scan(body, x, (params, caches))
+    (x, caches), _ = jax.lax.scan(body, (x, caches),
+                                  (params, jnp.arange(cfg.n_repeats)))
+    return x, caches

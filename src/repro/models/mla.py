@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from .common import ModelConfig
+from .attention import write_row
 from .layers import apply_rope, dense_init
 
 NEG_INF = -2.0e38
@@ -102,8 +103,10 @@ def apply_mla_prefill(p, x, cfg: ModelConfig):
     return out, {"c_kv": c_kv, "k_rope": k_rope}
 
 
-def apply_mla_decode(p, x, cache, cfg: ModelConfig, *, cache_index):
-    """Absorbed decode. cache: {"c_kv": (B,S,R), "k_rope": (B,S,rope)}."""
+def apply_mla_decode(p, x, cache, cfg: ModelConfig, *, cache_index, layer=None):
+    """Absorbed decode. cache: {"c_kv": (B,S,R), "k_rope": (B,S,rope)}, or
+    with ``layer`` the stacked (L,B,S,*); the new token's latent is written
+    at ``cache_index`` (of ``layer``). Returns (out, new_cache)."""
     B = x.shape[0]
     H = cfg.n_heads
     nope, rope, vdim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -111,10 +114,8 @@ def apply_mla_decode(p, x, cache, cfg: ModelConfig, *, cache_index):
     positions = jnp.full((1,), cache_index, dtype=jnp.int32)
     q_nope, q_rope = _queries(p, x, cfg, positions)  # (B,1,H,*)
     c_new, kr_new = _latent(p, x, cfg, positions)
-    c_kv = jax.lax.dynamic_update_slice(
-        cache["c_kv"], c_new.astype(cache["c_kv"].dtype), (0, cache_index, 0))
-    k_rope = jax.lax.dynamic_update_slice(
-        cache["k_rope"], kr_new.astype(cache["k_rope"].dtype), (0, cache_index, 0))
+    c_all, c_kv = write_row(cache["c_kv"], c_new, cache_index, layer)
+    kr_all, k_rope = write_row(cache["k_rope"], kr_new, cache_index, layer)
 
     wkv_b = p["wkv_b"].astype(cfg.dtype).reshape(R, H, nope + vdim)
     w_k, w_v = wkv_b[..., :nope], wkv_b[..., nope:]
@@ -132,7 +133,7 @@ def apply_mla_decode(p, x, cache, cfg: ModelConfig, *, cache_index):
     o_lat = jnp.einsum("bhs,bsr->bhr", w, c_kv.astype(jnp.float32))
     out = jnp.einsum("bhr,rhv->bhv", o_lat, w_v.astype(jnp.float32))
     out = out.reshape(B, 1, H * vdim).astype(cfg.dtype)
-    return out @ p["wo"].astype(cfg.dtype), {"c_kv": c_kv, "k_rope": k_rope}
+    return out @ p["wo"].astype(cfg.dtype), {"c_kv": c_all, "k_rope": kr_all}
 
 
 def make_empty_mla_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=None):

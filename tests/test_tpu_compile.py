@@ -125,3 +125,20 @@ def test_qwen3_generate_step_fits_one_chip(one_chip):
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 3e9 < mem.argument_size_in_bytes  # the full-width weights
     assert peak < HBM_BYTES
+
+
+@pytest.mark.parametrize("arch", ["qwen3_1p7b", "jamba_1p5_large_398b",
+                                  "falcon_mamba_7b"])
+def test_decode_loop_writes_cache_in_place(one_chip, arch):
+    """The bf16 generate program, compiled for the chip, moves no whole
+    cache stack and no whole layer slice in its decode loop (the checks
+    of ``test_decode_cache_in_place.py``), and keeps the sequence axis of
+    its KV stacks next to the minor one, where decode attention reads it."""
+    from test_decode_cache_in_place import (B, PROMPT, cache_traffic_faults,
+                                            generate_program)
+
+    program, params, caches = generate_program(arch, "bfloat16")
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), params)
+    payload = {"tokens": _sds((B, PROMPT), jnp.int32, one_chip)}
+    text = _compile(program.fn, params, payload).as_text()
+    assert cache_traffic_faults(text, caches, seq_second_minor=True) == []
