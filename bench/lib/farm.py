@@ -14,8 +14,10 @@ Traffic comes from one general generator that reads the mix's parameters:
 - ``closed``: every service keeps ``DEPTH_PER_SERVICE`` tasks queued; a
   task completes, the next is submitted.  The window opens on the first
   completion (the last of any seen together with it) and closes on the
-  first completion at least ``seconds`` later; it counts the tasks
-  completed after its opening, so it holds whole tasks only.
+  first completion at least ``seconds`` later after which the tasks
+  completed since the opening are a multiple of the number of chips; it
+  counts those tasks, so it holds whole tasks only, and on several chips
+  whole periods of each chip.
 - ``open``: Poisson arrivals at ``rate_per_s``, on one fixed schedule
   for every seed (quantiles of the exponential law, scaled to fill the
   window exactly, in a fixed shuffled order).  The window holds the
@@ -113,18 +115,12 @@ def peak_bytes(device) -> int:
 
 def program_config(cell: Cell):
     """The program's own ModelConfig for the cell, held against the
-    configuration file: a size that differs is an error."""
+    configuration file through its family's ``program_pairs``: a size
+    that differs is an error."""
     import repro.configs as cfgs
 
     cfg = cfgs.get(cell.config["arch"])
-    m = cell.model
-    pairs = {"d_model": m.hidden_size, "d_ff": m.intermediate_size,
-             "n_layers": m.num_hidden_layers,
-             "n_heads": m.num_attention_heads,
-             "n_kv_heads": m.num_key_value_heads, "head_dim": m.head_dim,
-             "vocab_size": m.vocab_size, "rope_theta": m.rope_theta,
-             "qk_norm": m.qk_norm, "param_dtype": m.dtype,
-             "compute_dtype": m.dtype, "tie_embeddings": True}
+    pairs = cell.family.program_pairs(cell.model)
     bad = {k: (getattr(cfg, k), v) for k, v in pairs.items()
            if getattr(cfg, k) != v}
     if bad:
@@ -162,8 +158,8 @@ def build(cell: Cell, seed: int, devices):
     cfg = program_config(cell)
     api = build_model(cfg)
     W.check_layout(jax.eval_shape(api.init, jax.random.PRNGKey(0)),
-                   cell.model)
-    params = W.make_on_device(seed, cell.model, devices[0])
+                   cell.family, cell.model)
+    params = W.make_on_device(seed, cell.family, cell.model, devices[0])
     t = cell.traffic
     sc = ServeConfig(max_new_tokens=t.new_tokens, prompt_len=t.prompt_len,
                      batch_per_task=t.prompts_per_task)
@@ -269,9 +265,10 @@ class _Driver:
 
 
 class _Closed(_Driver):
-    def __init__(self, *a, seconds: float):
+    def __init__(self, *a, seconds: float, chips: int):
         super().__init__(*a)
-        self.seconds = seconds
+        self.seconds, self.chips = seconds, chips
+        self.since_open = 0  # completions after the opening one
         self.opened = threading.Event()
         self.closed = threading.Event()
 
@@ -282,11 +279,18 @@ class _Closed(_Driver):
             # together: the earlier one may have ended on the chip a
             # whole task before, and none of them is counted
             s.t_open = now
+            self.since_open = 0
             self.opened.set()
-        elif s.t_close is None and now >= s.t_open + self.seconds:
-            s.t_close = now
-            self.stop_submitting = True
-            self.closed.set()
+        elif s.t_close is None:
+            self.since_open += 1
+            # each chip completes a task per period, in its own phase: a
+            # window that counts a multiple of the chips spans whole
+            # periods of every chip (on one chip, any completion does)
+            if (now >= s.t_open + self.seconds
+                    and self.since_open % self.chips == 0):
+                s.t_close = now
+                self.stop_submitting = True
+                self.closed.set()
         return s.t_close is None
 
 
@@ -353,7 +357,8 @@ def drive(cell: Cell, seed: int, seconds: float, program, services, lookup,
     tasks0 = {s.service_id: s.tasks_executed for s in services}
     trace_s = t.trace_seconds or seconds
     if t.loop == "closed":
-        drv = _Closed(ex, cell, seed, served, seconds=seconds)
+        drv = _Closed(ex, cell, seed, served, seconds=seconds,
+                      chips=len(devices))
         counter.on = True
         for _ in range(DEPTH_PER_SERVICE * len(services)):
             drv.submit()

@@ -1,73 +1,28 @@
 """What one cell is: its entry in ``BENCHMARK.json`` and the files that
 entry names.
 
-A cell is found by name.  Its configuration is
-``bench/configs/<config>.json``, its traffic mix
-``bench/traffic/<traffic>.json`` and its output check
-``bench/checks/<cell>.json``; nothing here knows any cell by name.
+A cell is found by name.  Its configuration is the file its
+``configs`` entry names (``bench/configs/<config>.json``), whose
+``"family"`` key names the model family ``bench/models/<family>.py``;
+its traffic mix is ``bench/traffic/<traffic>.json`` and its output check
+``bench/checks/<cell>.json``.  Nothing here knows any cell, or any
+model's sizes: a family module brings its ``Model`` and everything that
+reads it.
 """
 
 from __future__ import annotations
 
+import functools
+import hashlib
+import importlib.util
 import json
-import math
 import os
+import sys
 from dataclasses import dataclass, field
+from types import ModuleType
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-
-@dataclass(frozen=True)
-class Model:
-    """The sizes of a dense decoder, under the published config's keys."""
-
-    hidden_size: int
-    intermediate_size: int
-    num_hidden_layers: int
-    num_attention_heads: int
-    num_key_value_heads: int
-    head_dim: int
-    vocab_size: int
-    rms_norm_eps: float
-    rope_theta: float
-    qk_norm: bool = False
-    scale_emb: float = 1.0
-    scale_depth: float | None = None  # None: plain residual
-    dim_model_base: int | None = None  # None: no logit scaling
-    dtype: str = "bfloat16"
-
-    @classmethod
-    def from_config(cls, cfg: dict) -> "Model":
-        names = set(cls.__dataclass_fields__)
-        kw = {k: v for k, v in cfg.items() if k in names}
-        if "head_dim" not in kw:
-            kw["head_dim"] = cfg["hidden_size"] // cfg["num_attention_heads"]
-        if "torch_dtype" in cfg:
-            kw["dtype"] = cfg["torch_dtype"]
-        return cls(**kw)
-
-    @property
-    def residual_scale(self) -> float:
-        if self.scale_depth is None:
-            return 1.0
-        return self.scale_depth / math.sqrt(self.num_hidden_layers)
-
-    @property
-    def logit_scale(self) -> float:
-        if self.dim_model_base is None:
-            return 1.0
-        return self.dim_model_base / self.hidden_size
-
-    @property
-    def kv_bytes_per_token(self) -> int:
-        """Keys and values of one position, all layers, in ``dtype``."""
-        return (2 * self.num_hidden_layers * self.num_key_value_heads
-                * self.head_dim * self.bytes_per_value)
-
-    @property
-    def bytes_per_value(self) -> int:
-        return {"bfloat16": 2, "float16": 2, "float32": 4}[self.dtype]
 
 
 @dataclass(frozen=True)
@@ -101,15 +56,32 @@ class Cell:
     chips: int
     config_name: str
     config: dict  # the configuration file as it is run
-    model: Model
+    family: ModuleType  # bench/models/<family>.py
+    model: object  # family.Model.from_config(config)
     traffic_name: str
     traffic: Traffic
     check: dict  # limits of the output comparison
+    root: str = ROOT  # the checkout the cell's files were read from
 
 
 def _load(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+@functools.cache
+def family(name: str, root: str = ROOT) -> ModuleType:
+    """The model family ``bench/models/<name>.py`` under ``root``, loaded
+    by path once, as a module of its own per path (dataclasses find
+    their module in ``sys.modules``)."""
+    path = os.path.join(root, "bench", "models", f"{name}.py")
+    key = hashlib.sha256(os.path.abspath(path).encode()).hexdigest()[:12]
+    mod_spec = importlib.util.spec_from_file_location(
+        f"bench_family_{key}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    sys.modules[mod_spec.name] = mod
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def benchmark(root: str = ROOT) -> dict:
@@ -129,10 +101,12 @@ def cell(name: str, root: str = ROOT) -> Cell:
     traffic = _load(os.path.join(root, "bench", "traffic",
                                  f"{w['traffic']}.json"))
     check = _load(os.path.join(root, "bench", "checks", f"{name}.json"))
+    fam = family(config["family"], root)
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
-                config=config, model=Model.from_config(config),
+                config=config, family=fam,
+                model=fam.Model.from_config(config),
                 traffic_name=w["traffic"],
-                traffic=Traffic.from_file(traffic), check=check)
+                traffic=Traffic.from_file(traffic), check=check, root=root)
 
 
 def end_to_end_names(name: str, root: str = ROOT) -> list[str]:

@@ -43,7 +43,7 @@ from bench.lib.farm import log  # noqa: E402
 from bench.lib.peaks import peaks  # noqa: E402
 from bench.lib.stats import percentile  # noqa: E402
 
-ROWS_PER_REFERENCE_CHUNK_BYTES = 1.5e9  # attention scores of one chunk
+ROWS_PER_REFERENCE_CHUNK_BYTES = 1.5e9  # the reference's largest array
 
 
 class RunView:
@@ -70,7 +70,7 @@ class RunView:
 
 
 def read_metric(name: str, view: RunView):
-    path = os.path.join(ROOT, "bench", "metrics", f"{name}.py")
+    path = os.path.join(view.cell.root, "bench", "metrics", f"{name}.py")
     mod_spec = importlib.util.spec_from_file_location(
         f"bench_metric_{name}", path)
     mod = importlib.util.module_from_spec(mod_spec)
@@ -111,10 +111,10 @@ def check(served, cell, seed: int, *, quantize: bool = False) -> dict:
         prompts = np.stack([served.prompts[i][r] for i, r in picks])
         tokens = np.stack([served.served[i][r] for i, r in picks])
         T = t.prompt_len + t.new_tokens
-        per_row = 4 * m.num_attention_heads * T * T
+        per_row = cell.family.reference_row_bytes(m, T)
         chunk = max(1, int(ROWS_PER_REFERENCE_CHUNK_BYTES // per_row))
         t0 = time.monotonic()
-        gap = reference.served_gap(seed, m, prompts, tokens,
+        gap = reference.served_gap(seed, cell.family, m, prompts, tokens,
                                    quantize=quantize, chunk=chunk)
         log(f"reference: {len(picks)} rows, {gap['tokens']} served tokens "
             f"in {time.monotonic() - t0:.3f} s")
@@ -171,7 +171,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
             f"{len(served.lateness_s)} requests")
     res = {"attempted": len(set(served.in_window) | served.failed),
            "failed": len(served.failed), "metrics": {}}
-    bench = spec.benchmark()
+    bench = spec.benchmark(cell.root)
     units = {m["name"]: m["unit"]
              for m in bench["end_to_end"] + bench["per_layer"]}
     if not trace:
@@ -187,16 +187,17 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
                 lat.append(end - served.due[i])
             values["task_latency_p50_s"] = percentile(lat, 50)
             values["task_latency_p90_s"] = percentile(lat, 90)
-        for name in spec.end_to_end_names(cell.name):
+        for name in spec.end_to_end_names(cell.name, cell.root):
             res["metrics"][name] = {"value": values[name],
                                     "unit": units[name]}
     else:
         kind = devices[0].device_kind
         p = peaks(kind)
-        cost = flops.task(cell.model, t.prompts_per_task, t.prompt_len,
-                          t.new_tokens, p["flops_bf16"], p["hbm_bytes_per_s"])
+        cost = flops.task(cell.family, cell.model, t.prompts_per_task,
+                          t.prompt_len, t.new_tokens, p["flops_bf16"],
+                          p["hbm_bytes_per_s"])
         view = RunView(cell, served, cost, p)
-        for m in spec.per_layer_names(cell.name):
+        for m in spec.per_layer_names(cell.name, cell.root):
             v = read_metric(m["name"], view)
             if v is not None:
                 res["metrics"][m["name"]] = {"value": v, "unit": m["unit"]}

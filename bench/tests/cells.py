@@ -12,17 +12,13 @@ from bench.lib import farm, spec
 ROOT_DIR = spec.ROOT
 
 
-def small_cell(name: str, *, loop: str = "closed", **traffic) -> spec.Cell:
-    """The cell ``name`` with its model at reduced widths and small
-    traffic; :func:`patch_program` makes the program match."""
-    real = spec.cell(name)
-    cfg = reduced_config(real.config["arch"])
-    model = spec.Model(
-        hidden_size=cfg.d_model, intermediate_size=cfg.d_ff,
-        num_hidden_layers=cfg.n_layers, num_attention_heads=cfg.n_heads,
-        num_key_value_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-        vocab_size=cfg.vocab_size, rms_norm_eps=1e-6,
-        rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm, dtype="float32")
+def small_cell(name: str, *, loop: str = "closed", root: str = ROOT_DIR,
+               **traffic) -> spec.Cell:
+    """The cell ``name`` (of the checkout at ``root``) with its model at
+    reduced widths, by its family's ``reduced``, and small traffic;
+    :func:`patch_program` makes the program match."""
+    real = spec.cell(name, root)
+    model = real.family.reduced(reduced_config(real.config["arch"]))
     kw = dict(loop=loop, prompts_per_task=2, prompt_len=16, new_tokens=6,
               services_per_chip=2, farm={"max_batch": 1, "max_inflight": 1},
               trace_seconds=0.5, check_tasks_per_service=2,

@@ -41,6 +41,8 @@ def test_every_config_file(c):
         cfg = json.load(f)
     assert cfg["source"] == c["source"]
     assert cfg["reduced"] == c["reduced"]
+    assert os.path.exists(os.path.join(spec.ROOT, "bench", "models",
+                                       f"{cfg['family']}.py"))
     assert set(c) == {"name", "source", "file", "reduced", "why"}
 
 
@@ -59,3 +61,16 @@ def test_metrics():
                                            f"{m['name']}.py"))
     layers = {m["layer"] for m in BENCH["per_layer"]}
     assert all(len(x) <= 200 for x in layers)
+
+
+def test_cells_are_distinct():
+    """Each pair of configuration and traffic is one cell, and at most half
+    of the cells hold four chips."""
+    cells = BENCH["workloads"]
+    names = [w["name"] for w in cells]
+    pairs = [(w["config"], w["traffic"]) for w in cells]
+    assert len(names) == len(set(names))
+    assert len(pairs) == len(set(pairs))
+    assert sum(w["chips"] == 4 for w in cells) <= max(1, len(cells) // 2)
+    used = {w["config"] for w in cells}
+    assert used == {c["name"] for c in BENCH["configs"]}

@@ -29,7 +29,7 @@ def _readings(cell_name: str, seed: int) -> dict:
                             vocab_size=VOCAB)
     cfg = cfgs.get(cell.config["arch"]).replace(n_layers=LAYERS,
                                                 vocab_size=VOCAB)
-    params = jax.jit(weights.program_params, static_argnums=1)(
+    params = jax.jit(cell.family.program_params, static_argnums=1)(
         weights.seed_words(seed), m)
     program = make_generate_program(
         build(cfg), ServeConfig(max_new_tokens=N, prompt_len=P,
@@ -37,9 +37,9 @@ def _readings(cell_name: str, seed: int) -> dict:
     prompts = np.random.default_rng(seed).integers(0, VOCAB, (B, P),
                                                    dtype=np.int32)
     served = jax.jit(program.fn)(params, {"tokens": prompts})["generated"]
-    return reference.served_gap(seed, m, prompts, np.asarray(served),
-                                quantize=True) | {"limit": cell.check[
-                                    "max_logit_gap"]}
+    gap = reference.served_gap(seed, cell.family, m, prompts,
+                               np.asarray(served), quantize=True)
+    return gap | {"limit": cell.check["max_logit_gap"]}
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])

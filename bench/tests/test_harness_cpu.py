@@ -42,20 +42,22 @@ def test_command_refuses_without_a_chip():
 
 
 def test_farm_across_four_host_devices():
-    """A gen cell on 4 CPU devices (run in a child so that the device
-    count can be set): 8 services pulling from one repository, every
-    one of them serving checked answers."""
+    """The 4-chip cell on 4 CPU devices (run in a child so that the
+    device count can be set): 8 services pulling from one repository,
+    every one of them serving checked answers."""
     code = (
-        "import dataclasses, jax\n"
+        "import jax\n"
         "from bench import run\n"
         "from bench.tests.cells import patch_program, small_cell\n"
         "import pytest\n"
         "mp = pytest.MonkeyPatch()\n"
-        "cell = dataclasses.replace(small_cell('qwen3-gen-batch'), chips=4)\n"
+        "cell = small_cell('qwen3-farm-4chip')\n"
+        "assert cell.chips == 4\n"
         "patch_program(mp, cell)\n"
         "res = run.run_cell(cell, 2**31 + 3, 1.0, False, jax.devices()[:4],"
         " 0.0)\n"
         "assert res['correct'], res['check']\n"
+        "assert res['check']['idle_services']['value'] == 0\n"
         "print('OK', res['check']['idle_services'])\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
