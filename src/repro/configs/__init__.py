@@ -23,6 +23,7 @@ ARCH_IDS = [
     "whisper_tiny",
     "phi3_vision_4p2b",
     "jamba_1p5_large_398b",
+    "deepseek_v2_lite_ep4",
 ]
 
 _ALIASES = {
@@ -36,6 +37,7 @@ _ALIASES = {
     "whisper-tiny": "whisper_tiny",
     "phi-3-vision-4.2b": "phi3_vision_4p2b",
     "jamba-1.5-large-398b": "jamba_1p5_large_398b",
+    "deepseek-v2-lite-ep4": "deepseek_v2_lite_ep4",
 }
 
 
@@ -69,15 +71,22 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         opt_state_dtype="float32",
         max_seq_len=128,
     )
+    if cfg.first_dense_layers:
+        kw["n_layers"] += 1
+        kw["first_dense_layers"] = 1
     if cfg.moe is not None:
         kw["moe"] = dataclasses.replace(
             cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2),
-            dense_residual_ff=128 if cfg.moe.dense_residual else 0)
+            dense_residual_ff=128 if cfg.moe.dense_residual else 0,
+            d_ff_expert=64 if cfg.moe.d_ff_expert else 0)
+        if cfg.moe.n_held:  # a quarter of the router's experts, as here
+            kw["moe"] = dataclasses.replace(kw["moe"], n_experts=8, n_held=2,
+                                            held_offset=0)
     if cfg.ssm is not None:
         kw["ssm"] = SSMConfig(state_dim=4, conv_width=4, expand=2, dt_rank=8)
     if cfg.attention == "mla":
-        kw.update(q_lora_rank=32, kv_lora_rank=16, qk_rope_head_dim=8,
-                  qk_nope_head_dim=16, v_head_dim=16)
+        kw.update(q_lora_rank=32 if cfg.q_lora_rank else 0, kv_lora_rank=16,
+                  qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=16)
     if cfg.is_encoder_decoder:
         kw.update(n_encoder_layers=2, encoder_seq_len=16)
     if cfg.frontend == "vision":

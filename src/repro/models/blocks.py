@@ -3,7 +3,9 @@
 A model is ``cfg.pattern`` (a short tuple of BlockSpec) repeated
 ``cfg.n_repeats`` times.  Parameters of each pattern position are stacked
 along a leading (n_repeats,) axis and the forward pass is a single
-``lax.scan`` — HLO stays O(|pattern|) for 72-layer models.
+``lax.scan`` — HLO stays O(|pattern|) for 72-layer models.  The
+``cfg.first_dense_layers`` leading (attention, dense MLP) layers, stacked
+under ``lead`` in params and caches, run before the scan, one by one.
 """
 
 from __future__ import annotations
@@ -16,7 +18,10 @@ from . import attention as attn_mod
 from . import mla as mla_mod
 from . import mamba as mamba_mod
 from .layers import apply_dense_mlp, apply_norm, init_dense_mlp, init_norm
-from .moe import apply_moe, init_moe
+from .moe import apply_moe, init_moe, join_held, split_held
+
+
+LEAD = BlockSpec(mixer="attn", mlp="dense")
 
 
 # --------------------------------------------------------------------- #
@@ -56,7 +61,8 @@ def apply_block_train(p, x, cfg: ModelConfig, spec: BlockSpec, *,
     if spec.mixer == "attn":
         h = apply_norm(p["mixer_norm"], x, cfg)
         if cfg.attention == "mla":
-            h = mla_mod.apply_mla_train(p["attn"], h, cfg)
+            with jax.named_scope("mla"):
+                h = mla_mod.apply_mla_train(p["attn"], h, cfg)
         else:
             h = attn_mod.apply_attention_train(
                 p["attn"], h, cfg, window=_window_for(cfg, spec, long_context),
@@ -95,7 +101,8 @@ def apply_block_prefill(p, x, cfg: ModelConfig, spec: BlockSpec, *,
     if spec.mixer == "attn":
         h = apply_norm(p["mixer_norm"], x, cfg)
         if cfg.attention == "mla":
-            h, kv = mla_mod.apply_mla_prefill(p["attn"], h, cfg)
+            with jax.named_scope("mla"):
+                h, kv = mla_mod.apply_mla_prefill(p["attn"], h, cfg)
             pad = seq_budget - x.shape[1]
             kv = jax.tree_util.tree_map(
                 lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2)), kv)
@@ -143,9 +150,10 @@ def apply_block_decode(p, x, cache, cfg: ModelConfig, spec: BlockSpec, *,
     if spec.mixer == "attn":
         h = apply_norm(p["mixer_norm"], x, cfg)
         if cfg.attention == "mla":
-            h, kv = mla_mod.apply_mla_decode(p["attn"], h, cache["attn"], cfg,
-                                             cache_index=cache_index,
-                                             layer=layer)
+            with jax.named_scope("mla"):
+                h, kv = mla_mod.apply_mla_decode(
+                    p["attn"], h, cache["attn"], cfg, cache_index=cache_index,
+                    layer=layer)
         else:
             h, kv = attn_mod.apply_attention_decode(
                 p["attn"], h, cache["attn"], cfg, cache_index=cache_index,
@@ -182,15 +190,70 @@ def init_blocks(key, cfg: ModelConfig):
     for i, spec in enumerate(cfg.pattern):
         keys = jax.random.split(jax.random.fold_in(key, i), cfg.n_repeats)
         out[f"b{i}"] = jax.vmap(lambda k, s=spec: init_block(k, cfg, s))(keys)
+    if cfg.first_dense_layers:
+        keys = jax.random.split(jax.random.fold_in(key, len(cfg.pattern)),
+                                cfg.first_dense_layers)
+        out["lead"] = jax.vmap(lambda k: init_block(k, cfg, LEAD))(keys)
     return out
+
+
+def _split_lead(tree):
+    """(the scanned repeats' part of ``tree``, its ``lead`` stack or None)."""
+    if "lead" not in tree:
+        return tree, None
+    rest = dict(tree)
+    return rest, rest.pop("lead")
+
+
+def _lead_layer(lead, i: int):
+    return jax.tree_util.tree_map(lambda a: a[i], lead)
+
+
+def _split_held(params, cfg: ModelConfig):
+    """(``params`` without what the layer scan must not slice, that part by
+    pattern position or None): see ``moe.split_held``."""
+    rest, held = {}, {}
+    for name, block in params.items():
+        rest[name], part = split_held(block, cfg)
+        if part is not None:
+            held[name] = part
+    return (rest, held) if held else (params, None)
+
+
+def _join_held(layer_params, held, layer):
+    return {name: join_held(p, held.get(name), layer)
+            for name, p in layer_params.items()}
+
+
+def _scan_xs(params, held, cfg: ModelConfig):
+    """The layer scan's ``xs``: the stacked params, and each layer's index
+    where a part of them is held whole."""
+    return params if held is None else (params, jnp.arange(cfg.n_repeats))
+
+
+def _scan_step(inp, held):
+    """One layer's params from the layer scan's ``xs``."""
+    if held is None:
+        return inp
+    layer_params, layer = inp
+    return _join_held(layer_params, held, layer)
 
 
 def apply_blocks_train(params, x, cfg: ModelConfig, *, long_context=False,
                        use_rope=True, causal=True, block_skip=False):
     from repro.sharding.hints import shard_hint
 
-    def body(carry, layer_params):
+    params, lead = _split_lead(params)
+    for i in range(cfg.first_dense_layers):
+        x, _ = apply_block_train(_lead_layer(lead, i), x, cfg, LEAD,
+                                 long_context=long_context, use_rope=use_rope,
+                                 causal=causal, block_skip=block_skip)
+
+    params, held = _split_held(params, cfg)
+
+    def body(carry, inp):
         x, aux = carry
+        layer_params = _scan_step(inp, held)
         # pin the layer-boundary (remat-saved) activation layout; the
         # barrier also stops XLA from hoisting dtype converts of the whole
         # saved stack out of the backward loop (a 2x-3x peak-memory bug).
@@ -204,22 +267,38 @@ def apply_blocks_train(params, x, cfg: ModelConfig, *, long_context=False,
 
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params)
+    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                               _scan_xs(params, held, cfg))
     return x, aux
 
 
 def init_caches(cfg: ModelConfig, batch: int, seq_len: int):
     """Stacked (n_repeats leading axis) cache pytree."""
-    def one(spec):
+    def one(spec, n):
         c = init_block_cache(cfg, spec, batch, seq_len)
         return jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.n_repeats,) + a.shape), c)
-    return {f"b{i}": one(spec) for i, spec in enumerate(cfg.pattern)}
+            lambda a: jnp.broadcast_to(a[None], (n,) + a.shape), c)
+    out = {f"b{i}": one(spec, cfg.n_repeats)
+           for i, spec in enumerate(cfg.pattern)}
+    if cfg.first_dense_layers:
+        out["lead"] = one(LEAD, cfg.first_dense_layers)
+    return out
 
 
 def apply_blocks_prefill(params, x, cfg: ModelConfig, *, seq_budget,
                          long_context=False):
-    def body(x, layer_params):
+    params, lead = _split_lead(params)
+    lead_caches = []
+    for i in range(cfg.first_dense_layers):
+        x, c = apply_block_prefill(_lead_layer(lead, i), x, cfg, LEAD,
+                                   seq_budget=seq_budget,
+                                   long_context=long_context)
+        lead_caches.append(c)
+
+    params, held = _split_held(params, cfg)
+
+    def body(x, inp):
+        layer_params = _scan_step(inp, held)
         caches = {}
         for i, spec in enumerate(cfg.pattern):
             x, c = apply_block_prefill(layer_params[f"b{i}"], x, cfg, spec,
@@ -228,7 +307,11 @@ def apply_blocks_prefill(params, x, cfg: ModelConfig, *, seq_budget,
             caches[f"b{i}"] = c
         return x, caches
 
-    return jax.lax.scan(body, x, params)
+    x, caches = jax.lax.scan(body, x, _scan_xs(params, held, cfg))
+    if lead_caches:
+        caches["lead"] = jax.tree_util.tree_map(lambda *a: jnp.stack(a),
+                                                *lead_caches)
+    return x, caches
 
 
 def apply_blocks_decode(params, x, caches, cfg: ModelConfig, *, cache_index,
@@ -236,9 +319,19 @@ def apply_blocks_decode(params, x, caches, cfg: ModelConfig, *, cache_index,
     """The layer scan carries the stacked caches and each layer writes only
     its new token's row, so a step moves no whole cache slice (the stack as
     the scan's ``xs``/``ys`` would be read and written whole every step)."""
+    params, lead = _split_lead(params)
+    caches, lead_cache = _split_lead(caches)
+    for i in range(cfg.first_dense_layers):
+        x, lead_cache = apply_block_decode(
+            _lead_layer(lead, i), x, lead_cache, cfg, LEAD, layer=i,
+            cache_index=cache_index, long_context=long_context)
+    params, held = _split_held(params, cfg)
+
     def body(carry, inp):
         x, caches = carry
         layer_params, layer = inp
+        if held is not None:
+            layer_params = _join_held(layer_params, held, layer)
         caches = dict(caches)
         for i, spec in enumerate(cfg.pattern):
             x, caches[f"b{i}"] = apply_block_decode(
@@ -249,4 +342,6 @@ def apply_blocks_decode(params, x, caches, cfg: ModelConfig, *, cache_index,
 
     (x, caches), _ = jax.lax.scan(body, (x, caches),
                                   (params, jnp.arange(cfg.n_repeats)))
+    if lead_cache is not None:
+        caches = dict(caches, lead=lead_cache)
     return x, caches

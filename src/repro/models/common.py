@@ -2,14 +2,16 @@
 
 One ``ModelConfig`` dataclass covers the whole assigned pool: dense GQA
 transformers (llama3.2, qwen3, minicpm, phi-3-vision backbone), MLA
-(minicpm3), token-dropping MoE with optional dense residual (llama4-maverick,
-arctic, jamba), Mamba-1 SSM (falcon-mamba), the jamba hybrid interleave, and
-the whisper encoder-decoder backbone.
+(minicpm3, deepseek-v2 with YaRN), token-dropping MoE with optional dense
+residual (llama4-maverick, arctic, jamba), a dropless MoE layer over the
+experts one chip holds (deepseek-v2), Mamba-1 SSM (falcon-mamba), the jamba
+hybrid interleave, and the whisper encoder-decoder backbone.
 
 A model is described as a *block pattern* (a short tuple of ``BlockSpec``)
 repeated ``n_repeats`` times.  The forward pass lax.scan's over the repeats,
 so HLO size stays O(pattern) instead of O(layers) and 48-72 layer configs
-lower quickly.
+lower quickly.  ``first_dense_layers`` leading (attention, dense MLP) layers
+run before the scanned repeats (DeepSeek's ``first_k_dense_replace``).
 """
 
 from __future__ import annotations
@@ -26,7 +28,10 @@ Pytree = Any
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Token-dropping (capacity-factor) mixture-of-experts."""
+    """Mixture-of-experts: token-dropping (capacity-factor) by default, or
+    ``dropless`` over the ``n_held`` experts from ``held_offset`` that this
+    chip holds of the ``n_experts`` the router scores (expert parallelism;
+    the other shares live on other chips)."""
 
     n_experts: int
     top_k: int
@@ -43,6 +48,34 @@ class MoEConfig:
     dense_residual_ff: int = 0
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+    # routed expert width; 0 -> the model's d_ff
+    d_ff_expert: int = 0
+    # renormalise the top-k gates to sum to 1 (else keep them as they are)
+    renormalize: bool = True
+    dropless: bool = False
+    n_held: int = 0  # 0 -> all n_experts
+    held_offset: int = 0
+
+    def __post_init__(self) -> None:
+        if self.n_held and not self.dropless:
+            raise ValueError("only the dropless layer holds a share of the "
+                             "experts")
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -88,6 +121,7 @@ class ModelConfig:
     attention: str = "gqa"  # "gqa" | "mla" | "none"
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rope_yarn: YarnConfig | None = None
     # MLA (DeepSeek/MiniCPM3 style multi-head latent attention)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -97,6 +131,9 @@ class ModelConfig:
 
     moe: MoEConfig | None = None
     ssm: SSMConfig | None = None
+    # leading (attention, dense MLP at d_ff) layers before the pattern's
+    # repeats; counted in n_layers
+    first_dense_layers: int = 0
 
     # encoder-decoder (whisper backbone)
     is_encoder_decoder: bool = False
@@ -128,16 +165,17 @@ class ModelConfig:
             mlp = "moe" if self.moe is not None else "dense"
             mixer = "mamba" if self.family == "ssm" else "attn"
             object.__setattr__(self, "pattern", (BlockSpec(mixer=mixer, mlp=mlp),))
-        if self.n_layers % len(self.pattern) != 0:
+        if (self.n_layers - self.first_dense_layers) % len(self.pattern) != 0:
             raise ValueError(
-                f"{self.name}: n_layers={self.n_layers} not divisible by "
-                f"pattern length {len(self.pattern)}"
+                f"{self.name}: n_layers={self.n_layers} less "
+                f"{self.first_dense_layers} leading dense layers is not "
+                f"divisible by pattern length {len(self.pattern)}"
             )
 
     # ------------------------------------------------------------------ #
     @property
     def n_repeats(self) -> int:
-        return self.n_layers // len(self.pattern)
+        return (self.n_layers - self.first_dense_layers) // len(self.pattern)
 
     @property
     def d_inner(self) -> int:
@@ -192,10 +230,10 @@ class ModelConfig:
         return mult * self.d_model * ff
 
     def _moe_params(self) -> tuple[int, int]:
-        """(total, active) params of one MoE layer."""
+        """(total, active) params of one MoE layer: the experts held here."""
         assert self.moe is not None
-        e = self._dense_mlp_params()
-        total = self.moe.n_experts * e + self.d_model * self.moe.n_experts
+        e = self._dense_mlp_params(self.moe.d_ff_expert or None)
+        total = self.moe.held * e + self.d_model * self.moe.n_experts
         active = self.moe.top_k * e
         if self.moe.dense_residual:
             r = self._dense_mlp_params(self.moe.dense_residual_ff or self.d_ff)
@@ -242,6 +280,10 @@ class ModelConfig:
                 active += a
         total *= self.n_repeats
         active *= self.n_repeats
+        lead = self.first_dense_layers * (self._attn_params()
+                                          + self._dense_mlp_params())
+        total += lead
+        active += lead
         emb = self.vocab_size * self.d_model
         emb_total = emb if self.tie_embeddings else 2 * emb
         if self.is_encoder_decoder:

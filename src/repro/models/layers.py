@@ -8,6 +8,8 @@ initialized with jax.vmap over split keys.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -66,9 +68,43 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
-    freqs = rope_frequencies(x.shape[-1], theta)  # (hd/2,)
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(yarn, head_dim: int, theta: float) -> tuple[int, int]:
+    """The rope pairs [low, high) over which YaRN ramps from the original
+    frequencies to those divided by ``factor`` (DeepSeek-V2's
+    ``yarn_find_correction_range``)."""
+    def pair(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(pair(yarn.beta_fast)), 0),
+            min(math.ceil(pair(yarn.beta_slow)), head_dim - 1))
+
+
+def yarn_frequencies(head_dim: int, theta: float, yarn) -> jnp.ndarray:
+    """DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` inverse frequencies:
+    pairs below the correction range keep theirs, pairs above it take
+    theirs over ``factor``, with a linear ramp between."""
+    extra = rope_frequencies(head_dim, theta)
+    low, high = yarn_correction_range(yarn, head_dim, theta)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               freqs: jnp.ndarray | None = None) -> jnp.ndarray:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+    ``freqs``: the inverse frequencies (hd/2,), by default plain RoPE's."""
+    if freqs is None:
+        freqs = rope_frequencies(x.shape[-1], theta)  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     sin = jnp.sin(angles)[..., None, :]  # (..., seq, 1, hd/2)
     cos = jnp.cos(angles)[..., None, :]

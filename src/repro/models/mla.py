@@ -5,6 +5,9 @@ Train/prefill materialize per-head K/V from the latent; decode uses the
 compressed latent cache (kv_lora_rank + rope dims per token), which is the
 whole point of MLA for serving: the 32k-decode cache shrinks by ~an order of
 magnitude vs GQA.
+
+With ``cfg.rope_yarn`` the rope part takes YaRN's frequencies and the
+scores DeepSeek-V2's softmax scale, ``q_head_dim ** -0.5 * mscale ** 2``.
 """
 
 from __future__ import annotations
@@ -14,7 +17,7 @@ import jax.numpy as jnp
 
 from .common import ModelConfig
 from .attention import write_row
-from .layers import apply_rope, dense_init
+from .layers import apply_rope, dense_init, yarn_frequencies, yarn_mscale
 
 NEG_INF = -2.0e38
 
@@ -50,6 +53,28 @@ def _rms(x, scale, eps=1e-6):
             * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def _rope(x, positions, cfg: ModelConfig):
+    y = cfg.rope_yarn
+    if y is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    out = apply_rope(x, positions, cfg.rope_theta,
+                     yarn_frequencies(x.shape[-1], cfg.rope_theta, y))
+    amplitude = yarn_mscale(y.factor, y.mscale) / yarn_mscale(
+        y.factor, y.mscale_all_dim)
+    if amplitude == 1.0:
+        return out
+    return (out.astype(jnp.float32) * amplitude).astype(out.dtype)
+
+
+def score_factor(cfg: ModelConfig) -> float:
+    """The softmax scale over ``(nope + rope) ** -0.5``: YaRN's mscale
+    squared where ``mscale_all_dim`` is set, else 1."""
+    y = cfg.rope_yarn
+    if y is None or not y.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+
+
 def _queries(p, x, cfg: ModelConfig, positions):
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -61,7 +86,7 @@ def _queries(p, x, cfg: ModelConfig, positions):
     else:
         q = (x @ p["wq"].astype(dt)).reshape(B, S, H, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _rope(q_rope, positions, cfg)
     return q_nope, q_rope
 
 
@@ -71,7 +96,7 @@ def _latent(p, x, cfg: ModelConfig, positions):
     kv_a = x @ p["wkv_a"].astype(dt)
     c_kv = _rms(kv_a[..., : cfg.kv_lora_rank], p["kv_a_norm"])
     k_rope = kv_a[..., cfg.kv_lora_rank:][..., None, :]  # single rope head
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+    k_rope = _rope(k_rope, positions, cfg)
     return c_kv, k_rope[..., 0, :]
 
 
@@ -87,6 +112,8 @@ def apply_mla_train(p, x, cfg: ModelConfig):
     k_nope, v = kv[..., :nope], kv[..., nope:]
     # assemble effective q/k with rope part appended; K==H (no GQA in MLA)
     q = jnp.concatenate([q_nope, q_rope], -1)
+    if score_factor(cfg) != 1.0:  # the kernel scales by (nope+rope)**-0.5
+        q = (q.astype(jnp.float32) * score_factor(cfg)).astype(q.dtype)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                                   (B, S, H, rope))], -1)
     from repro.kernels import flash_attention_dispatch
@@ -125,7 +152,7 @@ def apply_mla_decode(p, x, cache, cfg: ModelConfig, *, cache_index, layer=None):
     s = jnp.einsum("bhr,bsr->bhs", q_abs, c_kv.astype(jnp.float32))
     s = s + jnp.einsum("bhp,bsp->bhs", q_rope[:, 0].astype(jnp.float32),
                        k_rope.astype(jnp.float32))
-    s = s * ((nope + rope) ** -0.5)
+    s = s * ((nope + rope) ** -0.5 * score_factor(cfg))
     S = c_kv.shape[1]
     mask = jnp.arange(S) <= cache_index
     s = jnp.where(mask[None, None, :], s, NEG_INF)

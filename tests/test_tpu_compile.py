@@ -127,8 +127,25 @@ def test_qwen3_generate_step_fits_one_chip(one_chip):
     assert peak < HBM_BYTES
 
 
+def test_deepseek_generate_one_prompt_compiles(one_chip):
+    """DeepSeek-V2-Lite's expert share at full width serving one prompt of
+    100 tokens: neither decode's 6 routed rows nor prefill's 600 fill whole
+    row tiles of the grouped matmul, whose rows are padded to them."""
+    api = build(cfgs.get("deepseek_v2_lite_ep4"))
+    params = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    sc = ServeConfig(max_new_tokens=8, prompt_len=100, batch_per_task=1)
+    program = make_generate_program(api, sc, params)
+    payload = {"tokens": _sds((1, 100), jnp.int32, one_chip)}
+    compiled = _compile(program.fn, params, payload)
+    _assert_kernel(compiled)
+    mem = compiled.memory_analysis()
+    assert 9e9 < mem.argument_size_in_bytes < HBM_BYTES
+
+
 @pytest.mark.parametrize("arch", ["qwen3_1p7b", "jamba_1p5_large_398b",
-                                  "falcon_mamba_7b"])
+                                  "falcon_mamba_7b", "deepseek_v2_lite_ep4"])
 def test_decode_loop_writes_cache_in_place(one_chip, arch):
     """The bf16 generate program, compiled for the chip, moves no whole
     cache stack and no whole layer slice in its decode loop (the checks
