@@ -13,6 +13,12 @@ The controller is deliberately simple: AIMD-style hill climbing toward a
 per-batch latency target.  Slow services (large ``speed_factor``) converge
 to small batches, fast services to large ones, which keeps the pull
 scheduler's load balancing sharp — a slow node never hoards a huge lease.
+A service on which one task alone already takes half the target or more
+(a model's generate program, say) can never land in the band, so its
+controller steers by four times the one-task time it observed instead:
+such a service batches what is queued while a batch costs little more
+than one task, and holds at one or two tasks where cost grows with batch
+size.
 """
 
 from __future__ import annotations
@@ -113,6 +119,24 @@ class AdaptiveBatchController:
       ``speed_factor`` (``max_batch / speed_factor``, power-of-two floor),
       so a node known to be k× slower can never hoard a full-size lease
       near the end of a stream.
+
+    Second regime: the target taken from the observed one-task time.
+    ``t1_s`` is the least elapsed time of any one-task batch (a lone task
+    runs unpadded whatever the lease size, so it measures one task's cost
+    exactly).  Once two one-task batches are recorded (the first may hold
+    its compile) and ``t1_s`` is at least half of ``target_latency_s``, no
+    batch can land in the band and halving is a no-op, so the band is set
+    by ``4 × t1_s`` instead: the batch grows while a full batch costs
+    under ``2 × t1_s`` and halves when one costs over ``4 × t1_s``.  A
+    service whose cost is flat in batch size climbs to its ceiling; one
+    whose cost is linear in batch size holds at one or two tasks.  The
+    band's upper edge leaves room for one batch of a neighbour service on
+    the same device ahead of this one, since the elapsed time includes
+    that wait.  The throughput jump still aims at ``target_latency_s``;
+    with no batch faster than ``t1_s``, at least half of it, the jump
+    never goes past plain doubling.  A service whose one task fits under half the
+    target never leaves the first regime (``t1_s`` only falls), so
+    ``target_latency_s`` keeps its meaning there.
     """
 
     def __init__(self, *, min_batch: int = 1, max_batch: int = 64,
@@ -127,6 +151,8 @@ class AdaptiveBatchController:
         self.last_latency_s: float | None = None
         self.throughput_ewma: float | None = None  # tasks / second
         self.batches_recorded = 0
+        self.t1_s: float | None = None  # least time of a one-task batch
+        self.one_task_batches = 0
 
     def next_batch(self) -> int:
         return self.batch
@@ -141,18 +167,32 @@ class AdaptiveBatchController:
         tput = n_tasks / max(elapsed_s, 1e-9)
         self.throughput_ewma = (tput if self.throughput_ewma is None
                                 else 0.7 * self.throughput_ewma + 0.3 * tput)
+        if n_tasks == 1:
+            self.one_task_batches += 1
+            self.t1_s = (elapsed_s if self.t1_s is None
+                         else min(self.t1_s, elapsed_s))
         # only steer from full-size batches; a tail batch of 2 tasks says
         # nothing about how a full lease would behave
         if n_tasks < self.batch:
             return
-        if elapsed_s < 0.5 * self.target_latency_s:
+        target = self.steer_target_s
+        if elapsed_s < 0.5 * target:
             grown = self.batch * 2
             suggestion = self._throughput_suggestion()
             if suggestion is not None:
                 grown = max(grown, suggestion)
             self.batch = min(grown, self.max_batch)
-        elif elapsed_s > self.target_latency_s:
+        elif elapsed_s > target:
             self.batch = max(self.batch // 2, self.min_batch)
+
+    @property
+    def steer_target_s(self) -> float:
+        """The latency the band is set by: ``target_latency_s``, or four
+        times ``t1_s`` once one task alone reaches half of it."""
+        if (self.one_task_batches >= 2
+                and self.t1_s >= 0.5 * self.target_latency_s):
+            return 4.0 * self.t1_s
+        return self.target_latency_s
 
     def _throughput_suggestion(self) -> int | None:
         """Batch size the observed throughput says would land exactly on
@@ -172,6 +212,8 @@ class AdaptiveBatchController:
             "last_latency_s": self.last_latency_s,
             "throughput_ewma": self.throughput_ewma,
             "batches_recorded": self.batches_recorded,
+            "t1_s": self.t1_s,
+            "steer_target_s": self.steer_target_s,
         }
 
 

@@ -75,7 +75,10 @@ class BasicClient:
             toward ``target_batch_latency_s`` (slow services get smaller
             leases); ``False`` always leases ``max_batch``.
         target_batch_latency_s
-            Latency target per batch for the adaptive controller.
+            Latency target per batch for the adaptive controller; on a
+            service where one task alone takes half of it or more, the
+            controller steers by four times the observed one-task time
+            instead.
         shards
             Number of independently-locked repository shards the job's
             task state is split over (``1`` = the single-lock repository;

@@ -86,8 +86,8 @@ JOB_KEYS = frozenset({
 #: ControlThread.snapshot() / engine["batching"][sid]
 BATCHING_KEYS = frozenset({
     "batch", "max_batch", "last_latency_s", "throughput_ewma",
-    "batches_recorded", "batches_dispatched", "cache_hits",
-    "cache_misses"})
+    "batches_recorded", "t1_s", "steer_target_s", "batches_dispatched",
+    "cache_hits", "cache_misses"})
 
 LEASE_TABLE_KEYS = frozenset({
     "speculative_issues", "straggler_speculations", "service_rates"})

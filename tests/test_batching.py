@@ -264,6 +264,57 @@ def test_controller_throughput_jump_skips_doubling_ladder():
     assert c.next_batch() > 8
 
 
+@pytest.mark.parametrize("curve,check", [
+    # cost flat in batch size: climbs to the ceiling
+    (lambda b, i: 0.16 + 0.004 * (b - 1),
+     lambda sizes: 16 in sizes[:10]),
+    # cost linear in batch size: holds at one or two tasks
+    (lambda b, i: 0.16 * b,
+     lambda sizes: max(sizes) <= 2),
+    # flat, every other batch waits 0.16 s behind a neighbour's
+    (lambda b, i: 0.16 + 0.16 * (i % 2),
+     lambda sizes: 16 in sizes
+     and min(sizes[sizes.index(16):]) >= 8),
+], ids=["flat", "linear", "flat-with-wait"])
+def test_controller_steers_by_one_task_time(curve, check):
+    """One task alone overruns half the 0.05 s target, so the controller
+    steers by four times the observed one-task time instead."""
+    c = AdaptiveBatchController(max_batch=16, target_latency_s=0.05)
+    sizes = []
+    for i in range(40):
+        b = c.next_batch()
+        c.record(b, curve(b, i))
+        sizes.append(c.next_batch())
+    assert check(sizes), sizes
+    assert c.t1_s == pytest.approx(0.16)
+    assert c.steer_target_s == pytest.approx(0.64)
+
+
+def test_controller_fast_services_keep_their_sequences():
+    """Services whose one task fits under half the target steer by the
+    configured target, batch for batch as before the second regime."""
+    cases = [(lambda b: 0.001 + 0.003 * b, [2, 4, 16] + [32] * 27),
+             (lambda b: 0.001 + 0.001 * b, [2, 4, 32] + [64] * 27),
+             (lambda b: 0.001 + 0.02 * b, [2] + [4] * 29)]
+    for latency_fn, expected in cases:
+        c = AdaptiveBatchController(max_batch=64, target_latency_s=0.1)
+        assert _converge(c, latency_fn) == expected
+        stats = c.stats()
+        assert stats["t1_s"] == pytest.approx(latency_fn(1))
+        assert stats["steer_target_s"] == 0.1
+
+
+def test_controller_first_one_task_batch_may_hold_a_compile():
+    """One slow one-task batch (its compile) does not switch a fast
+    service to the second regime; a second, warm one decides."""
+    c = AdaptiveBatchController(max_batch=16, target_latency_s=0.05)
+    c.record(1, 2.0)
+    assert c.steer_target_s == 0.05 and c.next_batch() == 1
+    c.record(1, 0.002)
+    assert c.t1_s == 0.002 and c.steer_target_s == 0.05
+    assert c.next_batch() == 2
+
+
 def test_controller_bad_bounds_rejected():
     with pytest.raises(ValueError):
         AdaptiveBatchController(min_batch=0)
@@ -424,3 +475,22 @@ def test_futures_executor_batched(cluster):
         futs = [ex.submit(jnp.asarray(i)) for i in range(12)]
         vals = [int(f.result(timeout=60)) for f in futs]
     assert vals == [i - 1 for i in range(12)]
+
+
+def test_batched_farm_shares_executions_when_one_task_overruns_target():
+    """Two services on one device run a program whose cost is flat in
+    batch size (the per-batch round-trip stand-in) and over the 0.05 s
+    target: a burst of queued tasks shares executions, exactly."""
+    from repro.core import FarmExecutor
+    from repro.obs import Observability
+    lookup = LookupService()
+    for _ in range(2):
+        Service(lookup, task_delay_s=0.08).start()
+    obs = Observability()
+    with FarmExecutor(Program(lambda x: x * 3 + 1, name="flat"),
+                      lookup=lookup, obs=obs, max_batch=16) as ex:
+        futs = [ex.submit(jnp.asarray(float(i))) for i in range(8)]
+        vals = [float(f.result(timeout=60)) for f in futs]
+    assert vals == [3.0 * i + 1 for i in range(8)]
+    sizes = [ev[3] for ev in obs.events() if ev[1] == "dispatch"]
+    assert max(sizes) > 1, sizes
