@@ -51,20 +51,6 @@ def run_scale(args) -> None:
               "--out", args.scale_out])
 
 
-def run_contention(args) -> None:
-    """The sharded-repository gate: real-thread lock contention for 1 vs
-    8 vs 32 shards (straggler-storm rescue throughput + lock-wait
-    meters) and the shards=1 golden-trace identity check; writes
-    ``BENCH_contention.json``.  CI runs a reduced sweep; the full curve
-    is produced locally with ``benchmarks/contention.py``."""
-    from benchmarks import contention as mod
-
-    mod.main(["--services", args.contention_services,
-              "--per-service", str(args.contention_per_service),
-              "--repeats", str(args.contention_repeats),
-              "--out", args.contention_out])
-
-
 def run_obs(args) -> None:
     """The telemetry-spine gate: recorder overhead vs tracing-disabled
     on the batched inproc path (≤3%), plus the heterogeneous-NoW Chrome
@@ -123,17 +109,6 @@ def main() -> None:
     ap.add_argument("--scale-services", type=int, default=200)
     ap.add_argument("--scale-tasks", type=int, default=100_000)
     ap.add_argument("--scale-out", default="BENCH_scale.json")
-    ap.add_argument("--contention", action="store_true",
-                    help="only run the sharded-repository contention "
-                         "gate (1/8/32 shards under real threads + "
-                         "shards=1 trace identity; writes "
-                         "BENCH_contention.json)")
-    ap.add_argument("--contention-services", default="32,96",
-                    help="service counts for --contention (the gate "
-                         "applies at the top count)")
-    ap.add_argument("--contention-per-service", type=int, default=128)
-    ap.add_argument("--contention-repeats", type=int, default=2)
-    ap.add_argument("--contention-out", default="BENCH_contention.json")
     ap.add_argument("--obs", action="store_true",
                     help="only run the telemetry-spine gate (recorder "
                          "overhead vs tracing-disabled + the hetero-NoW "
@@ -179,9 +154,6 @@ def main() -> None:
     if args.scale:
         run_scale(args)
         return
-    if args.contention:
-        run_contention(args)
-        return
     if args.obs:
         run_obs(args)
         return
@@ -192,7 +164,7 @@ def main() -> None:
         run_autotune(args)
         return
 
-    from benchmarks import (autotune, contention, elasticity,
+    from benchmarks import (autotune, elasticity,
                             engine_overhead, farm_scalability,
                             fault_tolerance, heterogeneous_now, kernels,
                             load_balance, multi_tenant, normal_form,
@@ -201,7 +173,7 @@ def main() -> None:
     print("name,us_per_call,derived")
     for mod in (farm_scalability, load_balance, fault_tolerance, normal_form,
                 elasticity, heterogeneous_now, multi_tenant, engine_overhead,
-                scale, contention, wire, observability, autotune, kernels):
+                scale, wire, observability, autotune, kernels):
         for name, us, derived in mod.bench():
             print(f"{name},{us:.1f},{derived}")
 

@@ -59,7 +59,7 @@ class BasicClient:
                  lease_s: float = 30.0, speculation: bool = True,
                  elastic: bool = True, max_batch: int = 1,
                  max_inflight: int = 1, adaptive_batching: bool = True,
-                 target_batch_latency_s: float = 0.05, shards: int = 1,
+                 target_batch_latency_s: float = 0.05,
                  clock=None, on_lease=None, obs=None):
         """Batching knobs (beyond-paper hot path; defaults reproduce the
         paper's one-task-per-round-trip dispatch exactly):
@@ -79,11 +79,6 @@ class BasicClient:
             service where one task alone takes half of it or more, the
             controller steers by four times the observed one-task time
             instead.
-        shards
-            Number of independently-locked repository shards the job's
-            task state is split over (``1`` = the single-lock repository;
-            raise for real-thread farms with many services contending on
-            one lock — see ``benchmarks/contention.py``).
         clock
             Every timestamp and blocking wait in the engine goes through
             this :class:`repro.core.clock.Clock`.  Default: wall clock.
@@ -120,7 +115,7 @@ class BasicClient:
             self.lookup, clock=self.clock, max_concurrent_jobs=1,
             lease_s=lease_s, speculation=speculation, max_batch=max_batch,
             max_inflight=max_inflight, adaptive_batching=adaptive_batching,
-            target_batch_latency_s=target_batch_latency_s, shards=shards,
+            target_batch_latency_s=target_batch_latency_s,
             on_lease=engine_on_lease, elastic=elastic, admit=self._admit,
             obs=obs)
         self.obs = obs

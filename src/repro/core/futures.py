@@ -39,7 +39,7 @@ class FarmExecutor:
                  lookup: LookupService | None = None, lease_s: float = 30.0,
                  speculation: bool = True, max_batch: int = 1,
                  max_inflight: int = 1, adaptive_batching: bool = True,
-                 target_batch_latency_s: float = 0.05, shards: int = 1,
+                 target_batch_latency_s: float = 0.05,
                  clock=None, on_lease=None, obs=None):
         from repro.farm import FarmScheduler
 
@@ -52,7 +52,7 @@ class FarmExecutor:
             clock=clock, max_concurrent_jobs=1, lease_s=lease_s,
             speculation=speculation, max_batch=max_batch,
             max_inflight=max_inflight, adaptive_batching=adaptive_batching,
-            target_batch_latency_s=target_batch_latency_s, shards=shards,
+            target_batch_latency_s=target_batch_latency_s,
             on_lease=engine_on_lease, obs=obs)
         self.obs = obs
         # the one job: an open stream (closed only at shutdown), results

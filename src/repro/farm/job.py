@@ -74,7 +74,7 @@ class Job:
                  target_batch_latency_s: float = 0.05,
                  on_lease: Callable | None = None,
                  reclaim_done: bool = True, collect_results: bool = True,
-                 shards: int = 1, obs=None):
+                 obs=None):
         """``reclaim_done``/``collect_results`` are the two memory knobs
         the single-tenant adapters flip: a farm job (both True is the
         default ``reclaim_done``) drops repository copies and buffers
@@ -106,7 +106,7 @@ class Job:
         self.repository = TaskRepository(
             [], lease_s=lease_s, streaming=True, clock=self.clock,
             on_complete=self._on_complete, on_lease=repo_on_lease,
-            reclaim_done=reclaim_done, shards=shards, obs=obs)
+            reclaim_done=reclaim_done, obs=obs)
 
         self._cond = threading.Condition()
         self._state = JobState.QUEUED
@@ -436,7 +436,6 @@ class Job:
                 "speculative_issues": repo["speculative_issues"],
                 "straggler_speculations": repo["straggler_speculations"],
                 "per_service": repo["per_service"],
-                "shards": repo["shards"],
                 "lock_wait_s": repo["lock_wait_s"],
                 "lock_hold_s": repo["lock_hold_s"],
                 "lock_contentions": repo["lock_contentions"],

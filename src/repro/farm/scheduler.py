@@ -110,7 +110,6 @@ class FarmScheduler:
                  max_batch: int = 1, max_inflight: int = 1,
                  adaptive_batching: bool = True,
                  target_batch_latency_s: float = 0.05,
-                 shards: int = 1,
                  on_lease: Callable | None = None,
                  elastic: bool = True,
                  admit: Callable[[ServiceDescriptor], bool] | None = None,
@@ -149,7 +148,7 @@ class FarmScheduler:
         self.defaults = dict(
             lease_s=lease_s, speculation=speculation, max_batch=max_batch,
             max_inflight=max_inflight, adaptive_batching=adaptive_batching,
-            target_batch_latency_s=target_batch_latency_s, shards=shards)
+            target_batch_latency_s=target_batch_latency_s)
         self.on_lease = on_lease
 
         self._lock = threading.RLock()

@@ -95,8 +95,6 @@ def chrome_trace_events(events: Iterable[tuple], *,
             continue
         elif kind == "speculate":
             instant(t, kind, ev[2], {"task": ev[3], "attempt": ev[4]})
-        elif kind == "steal":
-            instant(t, kind, ev[2], {"shard": ev[3], "home": ev[4]})
         elif kind in ("task-fail", "service-dead", "service-lost",
                       "reconnect"):
             instant(t, kind, ev[2])

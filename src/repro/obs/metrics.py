@@ -3,8 +3,8 @@
 One registry per :class:`~repro.obs.Observability` bundle.  Instruments
 are created once (registration takes a lock) and updated lock-cheap
 (one ``threading.Lock`` per instrument; hot-path producers usually
-already hold a shard or controller lock, so the instrument lock is
-uncontended).  ``snapshot()`` renders the whole registry as one
+already hold the repository or a controller lock, so the instrument
+lock is uncontended).  ``snapshot()`` renders the whole registry as one
 versioned, JSON-serializable tree — the shape the periodic JSONL dump
 and ``engine.stats()["metrics"]`` expose.
 

@@ -1,11 +1,11 @@
 """The documented shapes: event taxonomy and stats() key sets.
 
 Every ``stats()`` surface in the engine grew up separately and the keys
-drifted (``lock_wait_s`` here, ``wait_s`` there; per-shard vs summed
-counters).  This module is the single source of truth: the benchmark
-JSON consumers and the schema test (``tests/test_stats_schema.py``)
-both read these sets, so a silent rename breaks loudly in CI instead of
-silently zeroing a dashboard column.
+drifted (``lock_wait_s`` here, ``wait_s`` there).  This module is the
+single source of truth: the benchmark JSON consumers and the schema test
+(``tests/test_stats_schema.py``) both read these sets, so a silent
+rename breaks loudly in CI instead of silently zeroing a dashboard
+column.
 
 ``validate_stats_tree`` walks a FarmScheduler snapshot (the one shape
 every front-end embeds) and raises ``SchemaError`` naming the first
@@ -23,14 +23,12 @@ EVENT_KINDS = {
     # task lifecycle (repository)
     "task-submit": ("n, first_task_id", "TaskRepository.add_tasks"),
     "lease": ("service_id, ((task_id, attempt), ...)",
-              "RepositoryShard lease paths"),
-    "steal": ("service_id, shard_index, home_shard",
-              "TaskRepository facade (sharded cross-shard lease)"),
+              "TaskRepository lease paths"),
     "speculate": ("service_id, task_id, attempt",
-                  "RepositoryShard.try_speculate"),
+                  "TaskRepository.get_task / get_batch (speculation)"),
     "complete": ("service_id, ((task_id, lease_start), ...)",
-                 "RepositoryShard.complete_some"),
-    "expire": ("(task_id, ...)", "RepositoryShard lease-deadline scan"),
+                 "TaskRepository.complete / complete_batch"),
+    "expire": ("(task_id, ...)", "TaskRepository lease-deadline scan"),
     "expire-service": ("service_id, n", "TaskRepository.expire_service"),
     "task-fail": ("service_id, task_id", "TaskRepository.fail"),
     "cancel": ("n_dropped", "TaskRepository.cancel"),
@@ -74,14 +72,14 @@ LOCK_KEYS = frozenset({
 REPOSITORY_KEYS = frozenset({
     "tasks", "done", "cancelled", "pending", "leased", "reschedules",
     "peak_unfinished", "speculative_issues", "straggler_speculations",
-    "service_rates", "per_service", "shards"}) | LOCK_KEYS
+    "service_rates", "per_service"}) | LOCK_KEYS
 
 JOB_KEYS = frozenset({
     "job_id", "name", "state", "weight", "services", "service_time_s",
     "peak_unfinished", "submitted_at", "started_at", "finished_at",
     "tasks", "done", "pending", "leased", "cancelled", "reschedules",
-    "speculative_issues", "straggler_speculations", "per_service",
-    "shards"}) | LOCK_KEYS
+    "speculative_issues", "straggler_speculations",
+    "per_service"}) | LOCK_KEYS
 
 #: ControlThread.snapshot() / engine["batching"][sid]
 BATCHING_KEYS = frozenset({

@@ -250,8 +250,6 @@ def test_tracing_disabled_constructs_no_recorder(monkeypatch):
     assert "metrics" not in stats and "trace" not in stats
     assert client.obs is None and client.engine.obs is None
     assert client.repository._obs is None
-    for shard in client.repository._shards:
-        assert shard._obs is None
     # the deprecated on_lease hook still works without obs
     assert cluster.trace, "on_lease compatibility path stopped recording"
 

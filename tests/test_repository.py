@@ -171,49 +171,37 @@ def test_stale_pending_entry_does_not_re_lease_done_task():
 
 
 # ------------------------------------------------------------------ #
-# sharded facade (shards > 1)
+# the one repository: exactly-once, fan-in and progress under churn
 # ------------------------------------------------------------------ #
 
-def test_more_shards_than_tasks():
-    """Degenerate split: most shards own nothing, everything still
-    dispatches exactly once and aggregates correctly."""
-    repo = TaskRepository(list(range(3)), shards=8)
-    assert repo.n_shards == 8
+@pytest.mark.parametrize("n_tasks", [3, 40])
+def test_lone_service_drains_repository_exactly_once(n_tasks):
+    """One service drains the whole repository, each task exactly once,
+    and the stats aggregate agrees with what it was handed."""
+    repo = TaskRepository(list(range(n_tasks)))
     got = []
-    while True:
-        g = repo.get_task("s1", timeout=0.1, allow_speculation=False)
-        if g is None:
-            break
-        got.append(g)
-        repo.complete(g[0], g[1] * 2, "s1")
-    assert sorted(t for t, _ in got) == [0, 1, 2]
-    assert repo.all_done
-    assert repo.results() == [0, 2, 4]
-    st = repo.stats()
-    assert st["shards"] == 8 and st["done"] == 3 and st["leased"] == 0
-
-
-def test_sharded_work_steal_drains_sibling_shards():
-    """One service must drain the whole repository even though its home
-    shard owns only a fraction of the tasks."""
-    repo = TaskRepository(list(range(40)), shards=4)
-    seen = set()
     while True:
         g = repo.get_task("lone", timeout=0.1, allow_speculation=False)
         if g is None:
             break
-        seen.add(g[0])
-        repo.complete(g[0], None, "lone")
-    assert seen == set(range(40))
+        got.append(g[0])
+        repo.complete(g[0], g[1] * 2, "lone")
+    assert sorted(got) == list(range(n_tasks))
+    assert repo.all_done
+    assert repo.results() == [2 * t for t in range(n_tasks)]
+    st = repo.stats()
+    assert st["tasks"] == st["done"] == n_tasks
+    assert st["leased"] == st["pending"] == st["reschedules"] == 0
+    assert st["per_service"] == {"lone": n_tasks}
 
 
-def test_sharded_steal_exactly_once_under_churn_fuzz():
-    """Real threads stealing across shards while a churn thread expires
-    their services: every task completes exactly once, no lease leaks."""
+def test_exactly_once_under_churn_fuzz():
+    """Real threads leasing while a churn thread expires their services:
+    every task completes exactly once, no lease leaks."""
     import random
 
     n_tasks, n_workers = 400, 8
-    repo = TaskRepository(list(range(n_tasks)), lease_s=60.0, shards=8)
+    repo = TaskRepository(list(range(n_tasks)), lease_s=60.0)
     completions: list[int] = []
     reclock = threading.Lock()
     stop = threading.Event()
@@ -248,19 +236,19 @@ def test_sharded_steal_exactly_once_under_churn_fuzz():
     st = repo.stats()
     assert st["done"] == n_tasks and st["leased"] == 0
     assert st["pending"] == 0
+    assert len(repo.leases) == 0
 
 
-def test_sharded_expire_service_fans_out_leak_free():
-    """A dead service's leases live on several shards; one expire_service
-    call must requeue them all and leak nothing."""
-    repo = TaskRepository(list(range(12)), lease_s=60.0, shards=4)
-    dead = [repo.get_task("dead", allow_speculation=False)[0]
-            for _ in range(6)]
-    alive = [repo.get_task("alive", allow_speculation=False)[0]
-             for _ in range(6)]
-    alive_tid = alive[0]
-    assert len({t % 4 for t in dead}) > 1  # spans shards
+def test_expire_service_requeues_all_its_leases_leak_free():
+    """Dead leases interleaved with live ones: one expire_service call
+    requeues exactly the dead service's leases and leaks nothing."""
+    repo = TaskRepository(list(range(12)), lease_s=60.0)
+    dead, alive = [], []
+    for _ in range(6):
+        dead.append(repo.get_task("dead", allow_speculation=False)[0])
+        alive.append(repo.get_task("alive", allow_speculation=False)[0])
     assert repo.expire_service("dead") == 6
+    assert repo.expire_service("dead") == 0  # nothing left to expire
     st = repo.stats()
     assert st["leased"] == 6 and st["reschedules"] == 6
     reclaimed = set()
@@ -268,13 +256,15 @@ def test_sharded_expire_service_fans_out_leak_free():
         g = repo.get_task("rescuer", timeout=0.1, allow_speculation=False)
         reclaimed.add(g[0])
     assert reclaimed == set(dead)
-    assert repo.records[alive_tid].state.value == "leased"
+    assert all(repo.records[t].state.value == "leased" for t in alive)
+    assert repo.get_task("rescuer", timeout=0.05,
+                         allow_speculation=False) is None
 
 
-def test_sharded_cancel_fans_out_leak_free():
-    """cancel() on a sharded repository drops every shard's pending
-    queue and lease table; nothing dispatches afterwards."""
-    repo = TaskRepository(list(range(20)), lease_s=60.0, shards=4)
+def test_cancel_drops_pending_and_late_results():
+    """cancel() drops the pending queue and the lease table; nothing
+    dispatches afterwards and late results are dropped."""
+    repo = TaskRepository(list(range(20)), lease_s=60.0)
     leased = [repo.get_task("s1", allow_speculation=False)
               for _ in range(5)]
     assert repo.cancel() == 15  # 20 - 5 leased
@@ -282,8 +272,8 @@ def test_sharded_cancel_fans_out_leak_free():
     assert repo.all_done and repo.cancelled
     st = repo.stats()
     assert st["pending"] == 0 and st["leased"] == 0
+    assert len(repo.leases) == 0
     assert repo.get_task("s2", timeout=0.05) is None
-    # late results from the cancelled leases are dropped on every shard
     for tid, payload in leased:
         assert repo.complete(tid, payload, "s1") is False
     assert repo.stats()["done"] == 0
@@ -291,43 +281,30 @@ def test_sharded_cancel_fans_out_leak_free():
         repo.add_task("late")
 
 
-def test_sharded_batch_fills_across_shards():
-    """A batch may span shards (each slice leased under its own lock);
-    group compatibility holds across the whole batch."""
-    repo = TaskRepository(["a1", "b1", "a2", "b2", "a3", "b3"], shards=3)
+def test_batch_keeps_groups_over_interleaved_payloads():
+    """Interleaved payload groups: each batch holds one group only, and
+    the skipped tasks stay pending in their original order."""
+    repo = TaskRepository(["a1", "b1", "a2", "b2", "a3", "b3"])
     key = lambda p: p[0]  # noqa: E731
-    batch = repo.get_batch("s1", 6, compatible=key)
-    assert len(batch) == 3 and {p[0] for _, p in batch} == {"a"} or \
-        {p[0] for _, p in batch} == {"b"}
-    batch2 = repo.get_batch("s1", 6, compatible=key)
-    assert len(batch2) == 3
-    assert {p[0] for _, p in batch} != {p[0] for _, p in batch2}
+    # a full batch of 3 leaves b3 pending; b1 and b2 go back ahead of it
+    batch = repo.get_batch("s1", 3, compatible=key)
+    assert [p for _, p in batch] == ["a1", "a2", "a3"]
+    batch2 = repo.get_batch("s1", 3, compatible=key)
+    assert [p for _, p in batch2] == ["b1", "b2", "b3"]
+    assert repo.stats()["leased"] == 6
 
 
-def test_sharded_speculation_rescues_sibling_straggler():
-    """Speculative re-execution reaches leases on shards other than the
-    caller's home shard."""
-    import zlib
-
-    repo = TaskRepository(list(range(16)), lease_s=60.0,
-                          speculation_factor=0.0, shards=4)
-    # the age arm needs >= 3 observed durations per shard, and a leaser
-    # drains its home shard first — warm each shard through a service
-    # homed there (same stable crc32 hash the facade uses)
-    homes = {}
-    j = 0
-    while len(homes) < 4:
-        sid = f"warm{j}"
-        homes.setdefault(zlib.crc32(sid.encode()) % 4, sid)
-        j += 1
-    for k in range(4):
-        for _ in range(3):
-            tid, p = repo.get_task(homes[k], allow_speculation=False)
-            assert tid % 4 == k
-            repo.complete(tid, p, homes[k])
+def test_speculation_rescues_every_straggler():
+    """Every stuck lease gets a speculative duplicate, first result wins,
+    and the repository drains."""
+    repo = TaskRepository(list(range(7)), lease_s=60.0,
+                          speculation_factor=0.0)
+    # the age arm needs >= 3 observed durations
+    for _ in range(3):
+        tid, p = repo.get_task("warm", allow_speculation=False)
+        repo.complete(tid, p, "warm")
     stuck = {repo.get_task("slow", allow_speculation=False)[0]
              for _ in range(4)}
-    assert len({t % 4 for t in stuck}) > 1  # stragglers span shards
     rescued = set()
     for _ in range(4):
         g = repo.get_task("fast", timeout=0.5)
@@ -337,32 +314,32 @@ def test_sharded_speculation_rescues_sibling_straggler():
     assert rescued == stuck
     assert repo.stats()["speculative_issues"] == 4
     assert repo.all_done
+    for tid in stuck:  # the stragglers' late results are dropped
+        assert repo.complete(tid, "late", "slow") is False
 
 
 def test_lock_meters_in_stats():
-    """The contention instrumentation is always on and aggregates across
-    shards (sharded or not)."""
-    for shards in (1, 4):
-        repo = TaskRepository(list(range(10)), shards=shards)
-        while True:
-            g = repo.get_task("s1", timeout=0.05,
-                              allow_speculation=False)
-            if g is None:
-                break
-            repo.complete(g[0], None, "s1")
-        st = repo.stats()
-        assert st["lock_acquisitions"] > 0
-        assert st["lock_hold_s"] > 0.0
-        assert st["lock_wait_s"] >= 0.0
-        assert st["lock_contentions"] >= 0
-        assert st["shards"] == shards
+    """The contention instrumentation on the repository lock is always
+    on."""
+    repo = TaskRepository(list(range(10)))
+    while True:
+        g = repo.get_task("s1", timeout=0.05, allow_speculation=False)
+        if g is None:
+            break
+        repo.complete(g[0], None, "s1")
+    st = repo.stats()
+    assert st["lock_acquisitions"] > 0
+    assert st["lock_hold_s"] > 0.0
+    assert st["lock_wait_s"] >= 0.0
+    assert st["lock_contentions"] >= 0
+    assert "shards" not in st
 
 
-def test_sharded_streaming_backpressure_and_wait_all():
-    """The facade-level progress condition: a feeder throttled by
-    wait_unfinished_below and a watcher in wait_all both see sharded
-    completions."""
-    repo = TaskRepository([], streaming=True, shards=4)
+def test_streaming_backpressure_and_wait_all():
+    """The progress condition: a feeder throttled by
+    wait_unfinished_below and a watcher in wait_all both see the
+    consumer's completions."""
+    repo = TaskRepository([], streaming=True)
     done = threading.Event()
 
     def consumer():

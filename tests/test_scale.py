@@ -20,8 +20,7 @@ incremental rebalance work must preserve:
   identical objects until a membership event.
 """
 
-import os
-import sys
+import hashlib
 
 import pytest
 
@@ -41,7 +40,8 @@ def _churn_faults(n_services: int) -> dict[int, FaultSpec]:
 
 
 def _run_churn(seed: int, *, incremental: bool = True,
-               n_services: int = 96, n_tasks: int = 3000):
+               n_services: int = 96, n_tasks: int = 3000,
+               window: int = 2048):
     """One streaming job over a churning pool; returns the delivered
     {tid: result} map, both event traces, and the rebalance counters."""
     faults = _churn_faults(n_services)
@@ -55,7 +55,7 @@ def _run_churn(seed: int, *, incremental: bool = True,
         with sched:
             job = sched.submit(PROG, None, collect_results=True)
             job.submit_stream((float(i) for i in range(n_tasks)),
-                              window=2048)
+                              window=window)
             got = {}
             for tid, result in job.as_completed():
                 assert tid not in got, f"task {tid} delivered twice"
@@ -68,17 +68,27 @@ def _run_churn(seed: int, *, incremental: bool = True,
                             else None),
             }
             cluster.clock.sleep(5.0)  # drain silent-death hangs
+            repo = job.repository.stats()
+            counters["done"], counters["leased"] = repo["done"], repo["leased"]
             traces = (tuple(cluster.trace), tuple(sched.trace))
     return got, traces, counters
 
 
-def test_churn_exactly_once_and_deterministic():
-    got, traces, counters = _run_churn(11)
-    assert len(got) == 3000
+@pytest.mark.parametrize("n_services,n_tasks,window,seed",
+                         [(96, 3000, 2048, 11), (64, 2000, 1024, 31)])
+def test_churn_exactly_once_and_deterministic(n_services, n_tasks, window,
+                                              seed):
+    """Loud and silent deaths, late joins, batched leases, speculation:
+    every task delivered exactly once, and the same seed reproduces the
+    identical lease and scheduler traces."""
+    knobs = dict(n_services=n_services, n_tasks=n_tasks, window=window)
+    got, traces, counters = _run_churn(seed, **knobs)
+    assert len(got) == n_tasks
     for tid, result in got.items():
         assert float(result) == tid * 3.0 + 1.0
+    assert counters["done"] == n_tasks and counters["leased"] == 0
     # same seed, same everything — churn included
-    got2, traces2, counters2 = _run_churn(11)
+    got2, traces2, counters2 = _run_churn(seed, **knobs)
     assert got2 == got
     assert traces2 == traces
     assert counters2 == counters
@@ -163,56 +173,54 @@ def test_pool_membership_snapshots_cached_until_change():
 
 
 # ------------------------------------------------------------------ #
-# sharded repository (PR 7)
+# golden sim:// lease trace
 # ------------------------------------------------------------------ #
 
-def test_sharded_churn_exactly_once_and_deterministic():
-    """The full engine at shards=4 under the churn schedule: loud and
-    silent deaths, late joins, batched leases, speculation — every task
-    delivered exactly once, and the same seed reproduces the identical
-    lease trace (sharding must not leak nondeterminism into the sim)."""
-
-    def run(seed):
-        faults = _churn_faults(64)
-        with SimCluster(speed_factors=[1.0] * 64, seed=seed,
-                        base_cost_s=0.6 * 64 / 2000, latency_s=0.0,
-                        faults=faults, stall_timeout_s=120.0) as cluster:
-            sched = cluster.make_scheduler(
-                max_batch=8, max_inflight=1, adaptive_batching=False,
-                speculation=True, shards=4)
-            with sched:
-                job = sched.submit(PROG, None, collect_results=True)
-                job.submit_stream((float(i) for i in range(2000)),
-                                  window=1024)
-                got = {}
-                for tid, result in job.as_completed():
-                    assert tid not in got, f"task {tid} delivered twice"
-                    got[tid] = result
-                job.wait(timeout=300)
-                cluster.clock.sleep(5.0)
-                repo_stats = job.repository.stats()
-                trace = tuple(cluster.trace)
-        return got, trace, repo_stats
-
-    got, trace, stats = run(31)
-    assert len(got) == 2000
-    for tid, result in got.items():
-        assert float(result) == tid * 3.0 + 1.0
-    assert stats["shards"] == 4
-    assert stats["done"] == 2000 and stats["leased"] == 0
-    got2, trace2, stats2 = run(31)
-    assert got2 == got and trace2 == trace
+# SHA-256 over the golden sim:// scenario's lease trace, captured on the
+# single-lock engine.  Any change to lock scopes, wait sequences or lease
+# timestamps moves it: a refactor of the repository must reproduce it
+# byte for byte.
+GOLDEN_SHA256 = (
+    "272110425a85dabb62c84e5cd537dc298bee27c8993df7037af92d535ab4685e")
+GOLDEN_EVENTS = 808
 
 
-def test_shards_one_trace_identical_to_golden():
-    """shards=1 IS the pre-sharding repository: the golden churny sim
-    scenario's lease trace must match the hash pinned on the single-lock
-    engine, byte for byte."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from benchmarks.contention import (GOLDEN_EVENTS, GOLDEN_SHA256,
-                                       golden_run)
+def golden_run(seed: int = 17, n_services: int = 24, n_tasks: int = 800,
+               **job_knobs):
+    """One churny streaming job on the sim backend (deaths + a late
+    join, batched leases, speculation on); returns (results, trace hash,
+    event count).  Runs the REAL engine under the virtual clock — any
+    change to lock scopes, wait sequences, or lease timestamps shows up
+    in the hash."""
+    prog = Program(lambda x: x * 3.0 + 1.0, name="affine", jit=False)
+    faults = {0: FaultSpec(die_at=0.2), 1: FaultSpec(die_at=0.25),
+              n_services - 1: FaultSpec(register_at=0.15)}
+    with SimCluster(speed_factors=[1.0] * n_services, seed=seed,
+                    base_cost_s=0.5 * n_services / n_tasks, latency_s=0.0,
+                    faults=faults, stall_timeout_s=300.0) as cluster:
+        sched = cluster.make_scheduler(
+            max_batch=4, max_inflight=1, adaptive_batching=False,
+            speculation=True)
+        with sched:
+            job = sched.submit(prog, None, collect_results=True, **job_knobs)
+            job.submit_stream((float(i) for i in range(n_tasks)),
+                              window=256)
+            got = {}
+            for tid, result in job.as_completed():
+                got[tid] = result
+            job.wait(timeout=300)
+            cluster.clock.sleep(3.0)
+            trace = tuple(cluster.trace)
+    h = hashlib.sha256()
+    for item in trace:
+        h.update(repr(item).encode())
+    return got, h.hexdigest(), len(trace)
 
+
+def test_sim_lease_trace_identical_to_golden():
+    """The golden churny sim scenario's lease trace must match the hash
+    pinned on the single-lock engine, byte for byte."""
     got, digest, n_events = golden_run()
     assert len(got) == 800
     assert (digest, n_events) == (GOLDEN_SHA256, GOLDEN_EVENTS), (
-        "shards=1 sim lease trace diverged from the pre-sharding engine")
+        "sim lease trace diverged from the pinned single-lock engine")

@@ -30,7 +30,7 @@ def farm_snapshots():
     obs = Observability()
     with SimCluster(speed_factors=[1.0, 1.0, 2.0], seed=5,
                     base_cost_s=0.002, obs=obs) as cluster:
-        with cluster.make_scheduler(max_batch=4, shards=2) as sched:
+        with cluster.make_scheduler(max_batch=4) as sched:
             jobs = [sched.submit(PROGRAM, [float(i) for i in range(30)],
                                  weight=w) for w in (1.0, 2.0)]
             for job in jobs:
@@ -55,7 +55,7 @@ def test_job_and_repository_trees_match_schema(farm_snapshots):
         validate_job_stats(js)
     for rs in farm_snapshots["repos"]:
         validate_repository_stats(rs)
-        assert rs["shards"] == 2  # sharded facade reported its split
+        assert rs["done"] == rs["tasks"] == 30
 
 
 def test_virtual_clock_stats_match_schema(farm_snapshots):
